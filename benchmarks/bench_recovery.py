@@ -78,7 +78,7 @@ def _churn(svc: GraphService, rng, frac: float) -> int:
     return 2 * k
 
 
-def _crash_durable_service(sf: int, durable: str, warm_cc: str, rng):
+def _crash_durable_service(sf: int, durable: str, rng):
     """Run the doomed process: extract, churn, publish, churn, die.
 
     Returns ``(final_tables, reference_fingerprint)`` for the live (post
@@ -86,8 +86,7 @@ def _crash_durable_service(sf: int, durable: str, warm_cc: str, rng):
     """
     model = fraud_model("store")
     svc = GraphService(make_tpcds(sf=sf, seed=0), {MODEL_NAME: model},
-                       durable_dir=durable, persistent_cache=warm_cc,
-                       max_workers=2)
+                       durable_dir=durable, max_workers=2)
     try:
         svc.extract(MODEL_NAME)
         _churn(svc, rng, CHURN_FRACTION)
@@ -118,9 +117,11 @@ def run() -> List[Row]:
         workdir = tempfile.mkdtemp(prefix="bench_recovery_")
         try:
             durable = os.path.join(workdir, "durable")
-            warm_cc = os.path.join(workdir, "cc_warm")
+            # the compile cache the doomed process fills: the engine's
+            # default one (JAX_COMPILATION_CACHE_DIR, else the checkout's)
+            warm_cc = enable_persistent_compilation_cache()
             model, final_tables, ref_p, ref_final = _crash_durable_service(
-                sf, durable, warm_cc, rng)
+                sf, durable, rng)
 
             best_cold, best_restart, best_bd, replayed = (
                 float("inf"), float("inf"), {}, 0)
@@ -148,10 +149,11 @@ def run() -> List[Row]:
                 durable_rep = os.path.join(workdir, f"durable_{rep}")
                 shutil.copytree(durable, durable_rep)
 
+                enable_persistent_compilation_cache(warm_cc)
+
                 def _restart(durable_rep=durable_rep):
                     svc = GraphService(Database(), {MODEL_NAME: model},
                                        durable_dir=durable_rep,
-                                       persistent_cache=warm_cc,
                                        max_workers=2)
                     res = svc.extract(MODEL_NAME)
                     return svc, res

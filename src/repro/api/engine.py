@@ -433,12 +433,9 @@ class ExtractionEngine:
                  auto_refresh: bool = False,
                  refresh_threshold: float = 0.1,
                  max_results: int = 16,
-                 persistent_cache: Optional[str] = None,
                  cache_byte_budgets: Optional[Dict[str, int]] = None):
-        # opt-in on-disk XLA cache: an explicit path, or (when None) the
-        # REPRO_COMPILATION_CACHE env var; absent both this is a no-op
         from repro.core.pipeline import enable_persistent_compilation_cache
-        enable_persistent_compilation_cache(persistent_cache)
+        enable_persistent_compilation_cache()
         self.db = db
         self.max_plans = max_plans
         self.max_views = max_views

@@ -22,10 +22,11 @@ it:
   warm engine — or a warm executable cache replayed against cold data —
   skips re-tracing and re-compiling entirely.
 * **Pallas kernels** — with ``use_kernel`` (auto-on on TPU via
-  :func:`repro.kernels.ops.resolve_use_kernel`) the join probe runs the
-  ``sorted_probe`` kernel and each join prunes probe rows through a
-  ``bloom`` semi-join prefilter before the capacity expansion; off-TPU the
-  jnp reference paths are used.
+  :func:`repro.kernels.ops.resolve_use_kernel`) each join prunes probe rows
+  through a ``bloom`` semi-join prefilter before the capacity expansion;
+  the ``sorted_probe`` join probe runs only when ``use_kernel=True`` forces
+  it (the TPU default probes with XLA ``searchsorted``); off-TPU the jnp
+  reference paths are used.
 
 Bag semantics are identical to the eager path (the parity contract tested
 in ``tests/test_pipeline.py``): capacities only change padding, never the
@@ -35,6 +36,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import logging
 import os
 import queue
 import threading
@@ -44,6 +46,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.compilation_cache import compilation_cache
 
 from repro import obs
 from repro.core.cost import estimate_query, scan_estimate, step_expansions
@@ -90,61 +93,58 @@ def clear_executable_cache() -> None:
         _EXECUTABLE_CACHE.clear()
 
 
-# Opt-in on-disk XLA compilation cache ("cold-start elimination"): a
-# restarted server process re-lowers each unit but skips the XLA compile —
-# at SF=1 that is ~90% of a cold extract.  Enabled via the
-# REPRO_COMPILATION_CACHE env var or an explicit path (engine kwarg /
-# GraphService).  Process-global because the underlying JAX config is.
-PERSISTENT_CACHE_ENV = "REPRO_COMPILATION_CACHE"
+# On-disk XLA compilation cache ("cold-start elimination"): a restarted
+# process re-lowers each unit but skips the XLA compile.  One rule, applied
+# on engine start-up: where JAX_COMPILATION_CACHE_DIR is set JAX keeps its
+# cache there and no other directory is set in code; otherwise the cache
+# lives at a fixed directory of the checkout, because the path is part of
+# what a later process must find again.  Process-global because the
+# underlying JAX config is.
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), os.pardir, os.pardir,
+    os.pardir, ".jax_cache")
 _PERSISTENT_CACHE_DIR: Optional[str] = None
 _PERSISTENT_CACHE_LOCK = threading.Lock()
 
+log = logging.getLogger("repro.pipeline")
 
-def enable_persistent_compilation_cache(
-        path: Optional[str] = None) -> Optional[str]:
-    """Point JAX's persistent compilation cache at ``path`` (opt-in).
 
-    ``path`` defaults to the ``REPRO_COMPILATION_CACHE`` environment
-    variable; when neither is set this is a no-op returning ``None``.
-    Thresholds are lowered so even SF=1-sized executables are persisted —
-    the point is eliminating cold-start compiles, not saving disk.
-    Idempotent; returns the directory in effect.
+def enable_persistent_compilation_cache(path: Optional[str] = None) -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    The directory is ``JAX_COMPILATION_CACHE_DIR`` when that is set, else
+    :data:`DEFAULT_CACHE_DIR`.  Without ``path`` a cache already enabled
+    stays where it is.  ``path`` replaces only the default (the environment
+    variable still wins); it is for a benchmark that measures a cold-cache
+    restart on purpose.  Thresholds are lowered so even small unit
+    executables are persisted.
     """
     global _PERSISTENT_CACHE_DIR
-    path = path or os.environ.get(PERSISTENT_CACHE_ENV)
-    if not path:
-        return None
-    path = os.path.abspath(os.path.expanduser(path))
     with _PERSISTENT_CACHE_LOCK:
+        if path is None and _PERSISTENT_CACHE_DIR is not None:
+            return _PERSISTENT_CACHE_DIR
+        path = (os.environ.get(CACHE_DIR_ENV) or os.path.normpath(
+            os.path.abspath(path or DEFAULT_CACHE_DIR)))
         if _PERSISTENT_CACHE_DIR == path:
             return path
         os.makedirs(path, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", path)
-        for flag, value in (
-                ("jax_persistent_cache_min_compile_time_secs", 0.0),
-                ("jax_persistent_cache_min_entry_size_bytes", -1)):
-            try:
-                jax.config.update(flag, value)
-            except AttributeError:  # older jax: keep its default thresholds
-                pass
-        # jax initializes its cache object lazily on the first compile; a
-        # compile that happened before this call (data generation, another
-        # engine) pins it to the then-current dir — possibly *disabled* —
-        # and the config update alone never re-initializes it.  Force a
-        # re-init so enabling (or re-pointing) after warm-up still works.
-        try:
-            from jax._src import compilation_cache as _jax_cc
-            _jax_cc.reset_cache()
-        except Exception:       # pragma: no cover - older jax layouts
-            pass
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        # a cache JAX already opened keeps its directory until reset
+        compilation_cache.reset_cache()
         _PERSISTENT_CACHE_DIR = path
     return path
 
 
 def persistent_compilation_cache_dir() -> Optional[str]:
     """The directory enabled via :func:`enable_persistent_compilation_cache`
-    (``None`` when the feature is off)."""
+    (``None`` until the first engine starts)."""
     return _PERSISTENT_CACHE_DIR
+
+
+REOPT_FAILURES = "pipeline_reoptimization_failures_total"
 
 
 def _submit_reopt(job) -> None:
@@ -156,8 +156,12 @@ def _submit_reopt(job) -> None:
                     task = _REOPT_QUEUE.get()
                     try:
                         task()
-                    except Exception:   # pragma: no cover - best-effort
-                        pass
+                    except Exception:
+                        # the opt-0 build keeps serving; count and log so a
+                        # failed full-opt rebuild is never silent
+                        log.exception("background full-optimization "
+                                      "recompile failed")
+                        obs.failure_counter(REOPT_FAILURES).inc()
                     finally:
                         _REOPT_QUEUE.task_done()
 
@@ -172,7 +176,8 @@ def drain_reoptimizations(timeout: Optional[float] = None) -> None:
 
     Warm-path measurements should call this first: tiered cold builds leave
     full-optimization rebuilds in flight, and on small machines the rebuild
-    thread competes with whatever is being timed.
+    thread competes with whatever is being timed.  Rebuilds that failed are
+    counted in ``pipeline_reoptimization_failures_total``.
     """
     if _REOPT_THREAD is None:
         return
@@ -207,13 +212,7 @@ def tiered_compile(lowered, small: bool, store):
         compiler_options={"xla_backend_optimization_level": 0})
     store(exe)
 
-    def _reopt():
-        try:
-            store(lowered.compile())
-        except Exception:       # pragma: no cover - best-effort upgrade
-            pass
-
-    _submit_reopt(_reopt)
+    _submit_reopt(lambda: store(lowered.compile()))
     return exe
 
 
@@ -556,11 +555,12 @@ class PipelineCompiler:
     content-addressed store, so *any* compiler benefits from *any* prior
     compilation of the same (signature, capacities, schema) unit.
 
-    ``use_kernel=None`` auto-selects the Pallas ``sorted_probe`` join probe
-    on TPU and the jnp ``searchsorted`` path elsewhere;  ``use_bloom``
-    (default: follows ``use_kernel``) additionally prunes probe rows with
+    ``use_kernel=None`` auto-selects the Pallas kernels on TPU and the jnp
+    paths elsewhere (:func:`repro.kernels.ops.resolve_use_kernel`):
+    ``use_bloom`` (default: follows ``use_kernel``) prunes probe rows with
     the ``bloom`` semi-join prefilter kernel before each capacity
-    expansion.  ``initial_capacity_clamp`` caps the *initial* capacity
+    expansion, and ``probe_kernel`` (on only when ``use_kernel=True``)
+    routes the join probe through ``sorted_probe``.  ``initial_capacity_clamp`` caps the *initial* capacity
     buckets — production code never sets it; tests use it to force the
     overflow-retry branch.
     """
@@ -574,6 +574,7 @@ class PipelineCompiler:
                  tier_compile: bool = True):
         self.margin = float(margin)
         self.use_kernel = resolve_use_kernel(use_kernel)
+        self.probe_kernel = resolve_use_kernel(use_kernel, probe=True)
         self.use_bloom = self.use_kernel if use_bloom is None \
             else bool(use_bloom)
         self.max_programs = max_programs
@@ -680,12 +681,12 @@ class PipelineCompiler:
 
     def _executable(self, prog: UnitProgram, inputs: Dict[str, Table]):
         key = (prog.signature, prog.orders, prog.capacities,
-               self.use_kernel, self.use_bloom, _schema_fp(inputs))
+               self.probe_kernel, self.use_bloom, _schema_fp(inputs))
         tiered = (self.tier_compile
                   and max(prog.capacities, default=0) <= TIER_MAX_CAPACITY)
 
         def lower():
-            fn = _make_fn(prog, self.use_kernel, self.use_bloom)
+            fn = _make_fn(prog, self.probe_kernel, self.use_bloom)
             return jax.jit(fn).lower(inputs)
 
         t0 = time.perf_counter()
@@ -806,7 +807,7 @@ class PipelineCompiler:
             return "unknown"
         inputs = {n: tables[n] for n in prog.inputs}
         key = (prog.signature, prog.orders, prog.capacities,
-               self.use_kernel, self.use_bloom, _schema_fp(inputs))
+               self.probe_kernel, self.use_bloom, _schema_fp(inputs))
         with _CACHE_LOCK:
             return "cached" if key in _EXECUTABLE_CACHE else "uncompiled"
 

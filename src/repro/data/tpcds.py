@@ -1,9 +1,10 @@
 """Synthetic TPC-DS subset: the tables the paper's graph models touch.
 
-Real TPC-DS at SF=10 has ~28.8M store_sales rows; this container is a CPU,
-so we keep the paper's *ratios* and scale absolute row counts down by 1000x
-("SF 10" here = 28.8k fact rows).  Skew follows TPC-DS: fact foreign keys
-are drawn from a truncated Zipf so hot items/customers exist.
+Real TPC-DS at SF=10 has ~28.8M store_sales rows; ``sf`` here keeps the
+paper's *ratios* with absolute row counts scaled down by 1000x ("SF 10"
+here = 28.8k fact rows), so ``make_tpcds(sf=1000)`` has SF=1's published
+fact counts (2.88M / 1.44M / 0.72M sales rows).  Skew follows TPC-DS: fact
+foreign keys are drawn from a truncated Zipf so hot items/customers exist.
 
 Tables (per sales channel c in {store, catalog, web}):
   customer(rid, c_id, c_prop)            dimension

@@ -1,10 +1,12 @@
 """Shared tiling prologue for the COO edge kernels (spmv / label_prop /
-frontier): pad edge arrays to a whole number of TILE-sized input blocks,
-round the vertex axis up to SEG_BLOCK, and derive the 2-D accumulate grid.
+frontier): mask and pad edge arrays to a whole number of TILE-sized input
+blocks, round the vertex axis up to SEG_BLOCK, and derive the 2-D
+accumulate grid.
 
-Padding sentinels: ``src`` pads with 0 (always a safe gather index),
-``dst`` pads with -1 (never lands in any segment block), ``valid`` pads
-False — all three kernels rely on exactly this convention.
+The per-edge source values (``x[src]``) are gathered here, in XLA, before
+the ``pallas_call``: Mosaic lowers no 1-D gather inside a kernel.  Invalid
+and padding edges get ``dst = -1``, which lands in no segment block, so the
+kernels need no separate mask.
 """
 from __future__ import annotations
 
@@ -15,15 +17,19 @@ import jax.numpy as jnp
 
 
 def pad_coo(src: jax.Array, dst: jax.Array, valid: jax.Array,
-            num_vertices: int, tile: int, seg_block: int
-            ) -> Tuple[jax.Array, jax.Array, jax.Array, Tuple[int, int], int]:
-    """Returns ``(src, dst, valid, grid, s_pad)`` ready for ``pallas_call``."""
+            x: jax.Array, num_vertices: int, tile: int, seg_block: int,
+            fill) -> Tuple[jax.Array, jax.Array, Tuple[int, int], int]:
+    """Returns ``(dst, vals, grid, s_pad)`` ready for ``pallas_call``.
+
+    ``vals[e] = x[src[e]]`` for valid edges and ``fill`` elsewhere.
+    """
     n = src.shape[0]
     n_pad = ((n + tile - 1) // tile) * tile
     s_pad = ((num_vertices + seg_block - 1) // seg_block) * seg_block
-    src_p = jnp.pad(src.astype(jnp.int32), (0, n_pad - n), constant_values=0)
-    dst_p = jnp.pad(dst.astype(jnp.int32), (0, n_pad - n),
-                    constant_values=-1)
-    valid_p = jnp.pad(valid, (0, n_pad - n), constant_values=False)
+    src = jnp.clip(src.astype(jnp.int32), 0, x.shape[0] - 1)
+    vals = jnp.where(valid, x[src], jnp.asarray(fill, x.dtype))
+    dst = jnp.where(valid, dst.astype(jnp.int32), -1)
+    dst_p = jnp.pad(dst, (0, n_pad - n), constant_values=-1)
+    vals_p = jnp.pad(vals, (0, n_pad - n), constant_values=fill)
     grid = (s_pad // seg_block, n_pad // tile)
-    return src_p, dst_p, valid_p, grid, s_pad
+    return dst_p, vals_p, grid, s_pad

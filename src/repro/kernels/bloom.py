@@ -1,12 +1,13 @@
 """Pallas TPU kernel: Bloom-filter build/probe (semi-join prefilter).
 
-Beyond-paper optimization: before a distributed repartition join, each shard
-builds a Bloom filter of its build-side keys; probe-side rows that cannot
-match are dropped *before* the all_to_all, cutting the collective term of
-the roofline (see EXPERIMENTS.md §Perf).
+Beyond-paper optimization: the compiled pipeline builds a Bloom filter of
+each join's build-side keys and drops probe rows that cannot match before
+the capacity expansion (``relational/join.py:join_with_capacity``).
 
-Build uses the same one-hot/max trick as the histogram kernel (OR-scatter);
-probe re-hashes and gathers bits from the VMEM-resident bitset.
+Build uses the same one-hot/max trick as the histogram kernel (OR-scatter),
+with the bitset tiled over a grid axis like the histogram's segments.
+Probe re-hashes and reads its bits from the VMEM-resident bitset held as a
+(bits/128, 128) matrix: a one-hot row select on the MXU, then a lane pick.
 """
 from __future__ import annotations
 
@@ -17,6 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 TILE = 2048
+BITS_BLOCK = 2048     # bitset columns per build grid step
+LANES = 128           # probe-side bitset row width
 
 
 def _hash(ks: jnp.ndarray, num_bits: int, i: int) -> jnp.ndarray:
@@ -28,31 +31,44 @@ def _hash(ks: jnp.ndarray, num_bits: int, i: int) -> jnp.ndarray:
 
 def _build_kernel(keys_ref, valid_ref, bits_ref, *, num_bits: int,
                   num_hashes: int):
-    tile = pl.program_id(0)
+    bit_tile = pl.program_id(0)
+    inp_tile = pl.program_id(1)
 
-    @pl.when(tile == 0)
+    @pl.when(inp_tile == 0)
     def _init():
         bits_ref[...] = jnp.zeros_like(bits_ref)
 
     keys = keys_ref[...]
-    valid = valid_ref[...]
+    valid = valid_ref[...] != 0
+    block = bits_ref.shape[0]
+    positions = jnp.arange(block, dtype=jnp.int32)
     acc = bits_ref[...]
-    positions = jnp.arange(num_bits, dtype=jnp.int32)
     for i in range(num_hashes):
-        pos = _hash(keys, num_bits, i)
-        onehot = ((pos[:, None] == positions[None, :]) & valid[:, None])
-        acc = jnp.maximum(acc, onehot.any(axis=0).astype(jnp.int32))
+        # mask folded into the index (-1 hits no bit): Mosaic cannot
+        # broadcast a 1-D bool vector to a column
+        local = jnp.where(valid, _hash(keys, num_bits, i) - bit_tile * block,
+                          -1)
+        onehot = (local[:, None] == positions[None, :]).astype(jnp.int32)
+        acc = jnp.maximum(acc, jnp.max(onehot, axis=0))
     bits_ref[...] = acc
 
 
 def _probe_kernel(bits_ref, keys_ref, out_ref, *, num_bits: int,
                   num_hashes: int):
-    bits = bits_ref[...]
+    bits = bits_ref[...]                       # (num_bits // LANES, LANES)
     keys = keys_ref[...]
-    hit = jnp.ones(keys.shape, dtype=jnp.bool_)
+    rows = jnp.arange(bits.shape[0], dtype=jnp.int32)
+    lanes = jnp.arange(LANES, dtype=jnp.int32)
+    hit = jnp.ones(keys.shape, dtype=jnp.int32)
     for i in range(num_hashes):
         pos = _hash(keys, num_bits, i)
-        hit = hit & (jnp.take(bits, pos) > 0)
+        # 2-D gather bits[pos // LANES, pos % LANES]: a one-hot row select
+        # on the MXU (0/1 values, exact at any precision), then a lane pick
+        row_sel = (pos[:, None] // LANES == rows[None, :]).astype(jnp.float32)
+        picked = jnp.dot(row_sel, bits, preferred_element_type=jnp.float32)
+        lane_sel = (pos[:, None] % LANES) == lanes[None, :]
+        bit = jnp.sum(jnp.where(lane_sel, picked, 0.0), axis=1)
+        hit = hit * (bit > 0).astype(jnp.int32)
     out_ref[...] = hit
 
 
@@ -66,17 +82,18 @@ def bloom_build(keys: jax.Array, valid: jax.Array, num_bits: int,
         return jnp.zeros((num_bits,), jnp.int32)
     n_pad = ((n + TILE - 1) // TILE) * TILE
     ks = jnp.pad(keys.astype(jnp.int32), (0, n_pad - n))
-    vm = jnp.pad(valid, (0, n_pad - n), constant_values=False)
+    vm = jnp.pad(valid.astype(jnp.int32), (0, n_pad - n))
+    block = min(num_bits, BITS_BLOCK)
     kernel = functools.partial(_build_kernel, num_bits=num_bits,
                                num_hashes=num_hashes)
     return pl.pallas_call(
         kernel,
-        grid=(n_pad // TILE,),
+        grid=(num_bits // block, n_pad // TILE),
         in_specs=[
-            pl.BlockSpec((TILE,), lambda i: (i,)),
-            pl.BlockSpec((TILE,), lambda i: (i,)),
+            pl.BlockSpec((TILE,), lambda b, i: (i,)),
+            pl.BlockSpec((TILE,), lambda b, i: (i,)),
         ],
-        out_specs=pl.BlockSpec((num_bits,), lambda i: (0,)),
+        out_specs=pl.BlockSpec((block,), lambda b, i: (b,)),
         out_shape=jax.ShapeDtypeStruct((num_bits,), jnp.int32),
         interpret=interpret,
     )(ks, vm)
@@ -91,17 +108,18 @@ def bloom_probe(bits: jax.Array, keys: jax.Array, num_hashes: int = 2,
         return jnp.zeros((0,), jnp.bool_)
     n_pad = ((n + TILE - 1) // TILE) * TILE
     ks = jnp.pad(keys.astype(jnp.int32), (0, n_pad - n))
+    bits2d = bits.astype(jnp.float32).reshape(num_bits // LANES, LANES)
     kernel = functools.partial(_probe_kernel, num_bits=num_bits,
                                num_hashes=num_hashes)
     out = pl.pallas_call(
         kernel,
         grid=(n_pad // TILE,),
         in_specs=[
-            pl.BlockSpec((num_bits,), lambda i: (0,)),
+            pl.BlockSpec(bits2d.shape, lambda i: (0, 0)),     # stationary
             pl.BlockSpec((TILE,), lambda i: (i,)),
         ],
         out_specs=pl.BlockSpec((TILE,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n_pad,), jnp.bool_),
+        out_shape=jax.ShapeDtypeStruct((n_pad,), jnp.int32),
         interpret=interpret,
-    )(bits, ks)
-    return out[:n]
+    )(bits2d, ks)
+    return out[:n] > 0

@@ -3,7 +3,7 @@
 Computes ``next[v] = (exists valid edge (u -> v) with frontier[u]) and not
 visited[v]`` — one BFS level.  TPU adaptation: reached-neighbor counting is
 the same one-hot matmul as ``spmv`` (frontier membership as a 0/1 value
-gathered from a VMEM-resident mask, contracted with the one-hot destination
+per edge, gathered in XLA before the call, contracted with the one-hot destination
 matrix on the MXU); the ``~visited`` filter is applied once, on the last
 edge tile, after the counts for this vertex block have fully accumulated.
 
@@ -25,8 +25,7 @@ TILE = 1024
 SEG_BLOCK = 1024
 
 
-def _frontier_kernel(src_ref, dst_ref, valid_ref, frontier_ref, visited_ref,
-                     out_ref):
+def _frontier_kernel(dst_ref, front_ref, visited_ref, out_ref):
     seg_tile = pl.program_id(0)
     inp_tile = pl.program_id(1)
 
@@ -34,18 +33,10 @@ def _frontier_kernel(src_ref, dst_ref, valid_ref, frontier_ref, visited_ref,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    src = src_ref[...]
-    dst = dst_ref[...]
-    valid = valid_ref[...]
-    in_frontier = jnp.take(
-        frontier_ref[...], jnp.clip(src, 0, frontier_ref.shape[0] - 1))
-    base = seg_tile * SEG_BLOCK
-    local = dst - base
-    in_range = (local >= 0) & (local < SEG_BLOCK) & valid
+    local = dst_ref[...] - seg_tile * SEG_BLOCK
     onehot = (
-        (local[:, None] == jnp.arange(SEG_BLOCK, dtype=jnp.int32)[None, :])
-        & in_range[:, None]
-    ).astype(jnp.float32) * in_frontier.astype(jnp.float32)[:, None]
+        local[:, None] == jnp.arange(SEG_BLOCK, dtype=jnp.int32)[None, :]
+    ).astype(jnp.float32) * front_ref[...][:, None]
     out_ref[...] += jnp.dot(
         jnp.ones((1, onehot.shape[0]), jnp.float32), onehot,
         preferred_element_type=jnp.float32,
@@ -54,7 +45,7 @@ def _frontier_kernel(src_ref, dst_ref, valid_ref, frontier_ref, visited_ref,
     @pl.when(inp_tile == pl.num_programs(1) - 1)
     def _finalize():
         reached = out_ref[...] > 0
-        out_ref[...] = (reached & ~visited_ref[...]).astype(jnp.int32)
+        out_ref[...] = (reached & (visited_ref[...] == 0)).astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("num_vertices", "interpret"))
@@ -63,26 +54,25 @@ def frontier_expand(src: jax.Array, dst: jax.Array, valid: jax.Array,
                     num_vertices: int, interpret: bool = True) -> jax.Array:
     """One BFS level: bool mask of newly reached vertices.
 
-    ``interpret=True`` runs the kernel body in Python on CPU (this
-    container); on TPU pass ``interpret=False``.
+    ``interpret=True`` runs the kernel body in Python on the host (the CPU
+    backend); on TPU pass ``interpret=False``.
     """
-    src_p, dst_p, valid_p, grid, s_pad = pad_coo(
-        src, dst, valid, num_vertices, TILE, SEG_BLOCK)
-    front = frontier.astype(bool)
-    vis = jnp.pad(visited.astype(bool), (0, s_pad - num_vertices),
-                  constant_values=True)
+    dst_p, front_p, grid, s_pad = pad_coo(
+        src, dst, valid, frontier.astype(jnp.float32), num_vertices, TILE,
+        SEG_BLOCK, fill=0.0)
+    # int32, not bool: Mosaic loads no 1-D bool block
+    vis = jnp.pad(visited.astype(jnp.int32), (0, s_pad - num_vertices),
+                  constant_values=1)
     out = pl.pallas_call(
         _frontier_kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((TILE,), lambda s, i: (i,)),
             pl.BlockSpec((TILE,), lambda s, i: (i,)),
-            pl.BlockSpec((TILE,), lambda s, i: (i,)),
-            pl.BlockSpec((front.shape[0],), lambda s, i: (0,)),  # stationary
             pl.BlockSpec((SEG_BLOCK,), lambda s, i: (s,)),
         ],
         out_specs=pl.BlockSpec((SEG_BLOCK,), lambda s, i: (s,)),
         out_shape=jax.ShapeDtypeStruct((s_pad,), jnp.int32),
         interpret=interpret,
-    )(src_p, dst_p, valid_p, front, vis)
+    )(dst_p, front_p, vis)
     return out[:num_vertices] > 0

@@ -5,9 +5,9 @@ labels[u])`` — hook an undirected graph up by passing both edge directions
 and iterate to a fixed point for weakly connected components.
 
 TPU adaptation: the scatter-min over destination vertices is the masked-min
-variant of the one-hot idiom — each edge tile gathers source labels from a
-VMEM-resident ``labels``, builds the (TILE x SEG_BLOCK) one-hot destination
-mask, lifts non-members to +inf (INT32_MAX), and min-reduces over the edge
+variant of the one-hot idiom — each edge tile brings its source labels
+``labels[src]`` (gathered in XLA before the call, see ``_coo_tiling``),
+builds the (TILE x SEG_BLOCK) one-hot destination mask, lifts non-members to +inf (INT32_MAX), and min-reduces over the edge
 axis on the VPU, folding into the output block across grid steps.  The
 output block is initialized from the vertex's own label so the identity
 ``new <= labels`` holds even for isolated vertices.
@@ -30,8 +30,7 @@ SEG_BLOCK = 1024
 _INT_MAX = 2**31 - 1  # python int: jnp scalars would be captured as consts
 
 
-def _minlabel_kernel(src_ref, dst_ref, valid_ref, labels_ref, own_ref,
-                     out_ref):
+def _minlabel_kernel(dst_ref, lab_ref, own_ref, out_ref):
     seg_tile = pl.program_id(0)
     inp_tile = pl.program_id(1)
 
@@ -39,18 +38,9 @@ def _minlabel_kernel(src_ref, dst_ref, valid_ref, labels_ref, own_ref,
     def _init():
         out_ref[...] = own_ref[...]
 
-    src = src_ref[...]
-    dst = dst_ref[...]
-    valid = valid_ref[...]
-    lab = jnp.take(labels_ref[...], jnp.clip(src, 0, labels_ref.shape[0] - 1))
-    base = seg_tile * SEG_BLOCK
-    local = dst - base
-    in_range = (local >= 0) & (local < SEG_BLOCK) & valid
-    member = (
-        (local[:, None] == jnp.arange(SEG_BLOCK, dtype=jnp.int32)[None, :])
-        & in_range[:, None]
-    )
-    cand = jnp.where(member, lab[:, None], jnp.int32(_INT_MAX))
+    local = dst_ref[...] - seg_tile * SEG_BLOCK
+    member = local[:, None] == jnp.arange(SEG_BLOCK, dtype=jnp.int32)[None, :]
+    cand = jnp.where(member, lab_ref[...][:, None], jnp.int32(_INT_MAX))
     out_ref[...] = jnp.minimum(out_ref[...], jnp.min(cand, axis=0))
 
 
@@ -60,25 +50,24 @@ def edge_min_label(src: jax.Array, dst: jax.Array, valid: jax.Array,
                    interpret: bool = True) -> jax.Array:
     """One propagation step: ``min(labels[v], min_{(u,v)} labels[u])``.
 
-    ``interpret=True`` runs the kernel body in Python on CPU (this
-    container); on TPU pass ``interpret=False``.
+    ``interpret=True`` runs the kernel body in Python on the host (the CPU
+    backend); on TPU pass ``interpret=False``.
     """
-    src_p, dst_p, valid_p, grid, s_pad = pad_coo(
-        src, dst, valid, num_vertices, TILE, SEG_BLOCK)
     lab = labels.astype(jnp.int32)
-    own = jnp.pad(lab, (0, s_pad - num_vertices), constant_values=_INT_MAX)
+    dst_p, lab_p, grid, s_pad = pad_coo(
+        src, dst, valid, lab, num_vertices, TILE, SEG_BLOCK, fill=_INT_MAX)
+    own = jnp.pad(lab[:num_vertices], (0, s_pad - num_vertices),
+                  constant_values=_INT_MAX)
     out = pl.pallas_call(
         _minlabel_kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((TILE,), lambda s, i: (i,)),
             pl.BlockSpec((TILE,), lambda s, i: (i,)),
-            pl.BlockSpec((TILE,), lambda s, i: (i,)),
-            pl.BlockSpec((lab.shape[0],), lambda s, i: (0,)),  # stationary
             pl.BlockSpec((SEG_BLOCK,), lambda s, i: (s,)),
         ],
         out_specs=pl.BlockSpec((SEG_BLOCK,), lambda s, i: (s,)),
         out_shape=jax.ShapeDtypeStruct((s_pad,), jnp.int32),
         interpret=interpret,
-    )(src_p, dst_p, valid_p, lab, own)
+    )(dst_p, lab_p, own)
     return out[:num_vertices]

@@ -1,7 +1,9 @@
 """Jit'd public entry points for the Pallas kernels.
 
-``interpret`` defaults to True because this container is CPU-only; the
-launcher flips it to False on real TPU backends (see repro.launch).
+Each entry point compiles its kernel for the chip when JAX's default
+backend is a TPU (:func:`_on_tpu`), and runs it in Pallas interpret mode
+elsewhere, which is how the CPU test suite checks the kernels against
+their :mod:`repro.kernels.ref` oracles.
 """
 from __future__ import annotations
 
@@ -19,11 +21,19 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def resolve_use_kernel(use_kernel=None) -> bool:
+def resolve_use_kernel(use_kernel=None, *, probe: bool = False) -> bool:
     """The one kernel-vs-reference policy: ``None`` auto-picks — Pallas
     kernels on TPU, their jnp references elsewhere (interpret-mode Pallas
-    is emulation, not a fast path)."""
-    return _on_tpu() if use_kernel is None else bool(use_kernel)
+    is emulation, not a fast path); ``True``/``False`` force it.
+
+    The join probe (``probe=True``) is the exception: ``sorted_probe``
+    ranks by counting, work that grows with probe x build rows, so the TPU
+    default probes with XLA ``searchsorted`` and only ``True`` selects the
+    kernel.
+    """
+    if use_kernel is not None:
+        return bool(use_kernel)
+    return _on_tpu() and not probe
 
 
 def sorted_probe(sorted_keys, probe_keys):
@@ -56,7 +66,8 @@ def bloom_bits_for(build_capacity: int) -> int:
 
     ~2 bits per candidate key keeps the false-positive rate useful while the
     bitset stays VMEM-resident; clamped to [256, 16384] so tiny builds don't
-    underfill a tile and huge builds don't blow the stationary BlockSpec.
+    underfill a tile and the probe's stationary (bits/128, 128) block and
+    its one-hot row select stay small.
     """
     import math
 

@@ -33,11 +33,12 @@ def _hist_kernel(vals_ref, valid_ref, out_ref):
     valid = valid_ref[...]
     base = seg_tile * SEG_BLOCK
     local = vals - base
-    in_range = (local >= 0) & (local < SEG_BLOCK) & valid
+    # fold the mask into the index (-1 hits no column): Mosaic cannot
+    # broadcast a 1-D bool vector to a column
+    local = jnp.where(valid, local, -1)
     # one-hot contraction on the MXU: (TILE, SEG_BLOCK) x (TILE,) -> SEG_BLOCK
     onehot = (
-        (local[:, None] == jnp.arange(SEG_BLOCK, dtype=jnp.int32)[None, :])
-        & in_range[:, None]
+        local[:, None] == jnp.arange(SEG_BLOCK, dtype=jnp.int32)[None, :]
     ).astype(jnp.float32)
     out_ref[...] += jnp.dot(
         jnp.ones((1, onehot.shape[0]), jnp.float32), onehot,

@@ -1,52 +1,44 @@
-"""Pallas TPU kernel: vectorized two-sided binary search (join probe).
+"""Pallas TPU kernel: two-sided rank search (join probe).
 
-This is the probe phase of the sort-merge join — the hot loop of graph
-extraction.  TPU adaptation of the hash-probe PostgreSQL would run: instead
-of pointer chasing, each probe lane runs a branchless bisection over the
-sorted build keys held in VMEM; all lanes advance in lock-step (log2(S)
-iterations), which maps onto the VPU with no divergence.
+This is the probe phase of the sort-merge join.  For each probe key ``p``
+it returns ``lo = #{build keys < p}`` and ``hi = #{build keys <= p}`` over
+the sorted build side, the same ``(lo, hi)`` ranges as two
+``searchsorted`` calls.
 
-Tiling: probe keys are tiled over the grid (PROBE_BLOCK per step); the
-sorted build array is replicated into VMEM for every grid step (standard
-"stationary operand" BlockSpec).  For build sides larger than VMEM the
-wrapper falls back to a two-level scheme: a fence (block minima) search in
-the kernel selects the HBM block, which fits this same kernel recursively.
+TPU adaptation: Mosaic lowers no 1-D gather inside a kernel, so a
+lock-step bisection cannot read ``sorted[mid]``.  The kernel ranks by
+counting instead: probe keys are tiled over the first grid axis, the build
+side streams through VMEM in BUILD_BLOCK slices over the second, and every
+step adds the lane-parallel comparison counts of one (probe tile, build
+block) pair into the output block (the accumulate pattern of
+``segment_csr``).  It compiles at any build size, but its work grows with
+probe x build rows, so :func:`repro.kernels.ops.resolve_use_kernel` leaves
+it off the TPU default: the join probe there runs XLA ``searchsorted``.
 """
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 PROBE_BLOCK = 1024
+BUILD_BLOCK = 1024
+
+_INT_MAX = 2**31 - 1  # python int: jnp scalars would be captured as consts
 
 
-def _bisect(sorted_block: jnp.ndarray, probes: jnp.ndarray, side: str,
-            n_sorted: int) -> jnp.ndarray:
-    """Branchless lock-step bisection; sorted_block is a VMEM-resident row."""
-    lo = jnp.zeros(probes.shape, jnp.int32)
-    hi = jnp.full(probes.shape, n_sorted, jnp.int32)
-    steps = max(1, int(math.ceil(math.log2(max(n_sorted, 2)))) + 1)
-    for _ in range(steps):
-        mid = (lo + hi) // 2
-        mid_val = jnp.take(sorted_block, jnp.clip(mid, 0, n_sorted - 1))
-        if side == "left":
-            go_right = mid_val < probes
-        else:
-            go_right = mid_val <= probes
-        lo = jnp.where(go_right & (lo < hi), mid + 1, lo)
-        hi = jnp.where(~go_right & (lo < hi), mid, hi)
-    return lo
+def _rank_kernel(sorted_ref, probe_ref, lo_ref, hi_ref):
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        lo_ref[...] = jnp.zeros_like(lo_ref)
+        hi_ref[...] = jnp.zeros_like(hi_ref)
 
-
-def _probe_kernel(sorted_ref, probe_ref, lo_ref, hi_ref, *, n_sorted: int):
-    sorted_block = sorted_ref[...]
-    probes = probe_ref[...]
-    lo_ref[...] = _bisect(sorted_block, probes, "left", n_sorted)
-    hi_ref[...] = _bisect(sorted_block, probes, "right", n_sorted)
+    keys = sorted_ref[...][None, :]
+    probes = probe_ref[...][:, None]
+    lo_ref[...] += jnp.sum((keys < probes).astype(jnp.int32), axis=1)
+    hi_ref[...] += jnp.sum((keys <= probes).astype(jnp.int32), axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -54,8 +46,8 @@ def sorted_probe(sorted_keys: jax.Array, probe_keys: jax.Array,
                  interpret: bool = True):
     """(lo, hi) match ranges of each probe key in ``sorted_keys``.
 
-    ``interpret=True`` runs the kernel body in Python on CPU (this
-    container); on TPU pass ``interpret=False``.
+    ``interpret=True`` runs the kernel body in Python on the host (the CPU
+    backend); on TPU pass ``interpret=False``.
     """
     n_sorted = sorted_keys.shape[0]
     n_probe = probe_keys.shape[0]
@@ -65,26 +57,29 @@ def sorted_probe(sorted_keys: jax.Array, probe_keys: jax.Array,
     if n_sorted == 0:
         zeros = jnp.zeros((n_probe,), jnp.int32)
         return zeros, zeros
-    padded = ((n_probe + PROBE_BLOCK - 1) // PROBE_BLOCK) * PROBE_BLOCK
-    probe_padded = jnp.pad(probe_keys, (0, padded - n_probe),
-                           constant_values=0)
-    grid = (padded // PROBE_BLOCK,)
-    kernel = functools.partial(_probe_kernel, n_sorted=n_sorted)
+    p_pad = ((n_probe + PROBE_BLOCK - 1) // PROBE_BLOCK) * PROBE_BLOCK
+    s_pad = ((n_sorted + BUILD_BLOCK - 1) // BUILD_BLOCK) * BUILD_BLOCK
+    probe_padded = jnp.pad(probe_keys.astype(jnp.int32), (0, p_pad - n_probe))
+    # int32-max padding sorts last; it can only be counted by a probe equal
+    # to int32 max, and the clamp below removes it again
+    sorted_padded = jnp.pad(sorted_keys.astype(jnp.int32),
+                            (0, s_pad - n_sorted), constant_values=_INT_MAX)
     lo, hi = pl.pallas_call(
-        kernel,
-        grid=grid,
+        _rank_kernel,
+        grid=(p_pad // PROBE_BLOCK, s_pad // BUILD_BLOCK),
         in_specs=[
-            pl.BlockSpec((n_sorted,), lambda i: (0,)),        # stationary
-            pl.BlockSpec((PROBE_BLOCK,), lambda i: (i,)),
+            pl.BlockSpec((BUILD_BLOCK,), lambda i, b: (b,)),
+            pl.BlockSpec((PROBE_BLOCK,), lambda i, b: (i,)),
         ],
         out_specs=[
-            pl.BlockSpec((PROBE_BLOCK,), lambda i: (i,)),
-            pl.BlockSpec((PROBE_BLOCK,), lambda i: (i,)),
+            pl.BlockSpec((PROBE_BLOCK,), lambda i, b: (i,)),
+            pl.BlockSpec((PROBE_BLOCK,), lambda i, b: (i,)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((padded,), jnp.int32),
-            jax.ShapeDtypeStruct((padded,), jnp.int32),
+            jax.ShapeDtypeStruct((p_pad,), jnp.int32),
+            jax.ShapeDtypeStruct((p_pad,), jnp.int32),
         ],
         interpret=interpret,
-    )(sorted_keys, probe_padded)
-    return lo[:n_probe], hi[:n_probe]
+    )(sorted_padded, probe_padded)
+    return (jnp.minimum(lo[:n_probe], n_sorted),
+            jnp.minimum(hi[:n_probe], n_sorted))

@@ -3,16 +3,15 @@
 Computes ``y[v] = sum over valid edges (u -> v) of x[u]`` — the inner loop
 of push PageRank (and any edge-weighted aggregation once the caller folds
 the weight into ``x``).  TPU adaptation: the scatter-add over destination
-vertices is lowered as a **one-hot matmul** — each edge tile gathers its
-source values from a VMEM-resident ``x``, builds a one-hot (TILE x
-SEG_BLOCK) destination matrix scaled by those values, and contracts it with
-a ones-vector on the MXU, accumulating over grid steps into the output
-block (the same idiom as ``segment_csr``, generalized from counts to
-weighted sums).
+vertices is lowered as a **one-hot matmul** — each edge tile brings its
+source values ``x[src]`` (gathered in XLA before the call, see
+``_coo_tiling``), builds a one-hot (TILE x SEG_BLOCK) destination matrix
+scaled by those values, and contracts it with a ones-vector on the MXU,
+accumulating over grid steps into the output block (the same idiom as
+``segment_csr``, generalized from counts to weighted sums).
 
 Grid = (vertices/SEG_BLOCK, edges/TILE); the output block for a given
-vertex tile is revisited across all edge tiles (accumulate pattern).  ``x``
-rides along as a stationary operand so the gather stays in VMEM.
+vertex tile is revisited across all edge tiles (accumulate pattern).
 """
 from __future__ import annotations
 
@@ -28,7 +27,7 @@ TILE = 1024
 SEG_BLOCK = 1024
 
 
-def _spmv_kernel(src_ref, dst_ref, valid_ref, x_ref, out_ref):
+def _spmv_kernel(dst_ref, vals_ref, out_ref):
     seg_tile = pl.program_id(0)
     inp_tile = pl.program_id(1)
 
@@ -36,21 +35,17 @@ def _spmv_kernel(src_ref, dst_ref, valid_ref, x_ref, out_ref):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    src = src_ref[...]
-    dst = dst_ref[...]
-    valid = valid_ref[...]
-    vals = jnp.take(x_ref[...], jnp.clip(src, 0, x_ref.shape[0] - 1))
-    base = seg_tile * SEG_BLOCK
-    local = dst - base
-    in_range = (local >= 0) & (local < SEG_BLOCK) & valid
+    local = dst_ref[...] - seg_tile * SEG_BLOCK
     # scaled one-hot contraction on the MXU:
     #   (1, TILE) x (TILE, SEG_BLOCK) -> (SEG_BLOCK,)
+    # HIGHEST keeps the f32 values exact; the default precision rounds
+    # them to bf16 on the MXU
     onehot = (
-        (local[:, None] == jnp.arange(SEG_BLOCK, dtype=jnp.int32)[None, :])
-        & in_range[:, None]
-    ).astype(jnp.float32) * vals[:, None]
+        local[:, None] == jnp.arange(SEG_BLOCK, dtype=jnp.int32)[None, :]
+    ).astype(jnp.float32) * vals_ref[...][:, None]
     out_ref[...] += jnp.dot(
         jnp.ones((1, onehot.shape[0]), jnp.float32), onehot,
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )[0]
 
@@ -61,23 +56,21 @@ def edge_spmv(src: jax.Array, dst: jax.Array, valid: jax.Array,
               interpret: bool = True) -> jax.Array:
     """``y[v] = sum_{valid (u,v)} x[u]`` over COO edge arrays.
 
-    ``interpret=True`` runs the kernel body in Python on CPU (this
-    container); on TPU pass ``interpret=False``.
+    ``interpret=True`` runs the kernel body in Python on the host (the CPU
+    backend); on TPU pass ``interpret=False``.
     """
-    src_p, dst_p, valid_p, grid, s_pad = pad_coo(
-        src, dst, valid, num_vertices, TILE, SEG_BLOCK)
-    x_f = x.astype(jnp.float32)
+    dst_p, vals_p, grid, s_pad = pad_coo(
+        src, dst, valid, x.astype(jnp.float32), num_vertices, TILE,
+        SEG_BLOCK, fill=0.0)
     out = pl.pallas_call(
         _spmv_kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((TILE,), lambda s, i: (i,)),
             pl.BlockSpec((TILE,), lambda s, i: (i,)),
-            pl.BlockSpec((TILE,), lambda s, i: (i,)),
-            pl.BlockSpec((x_f.shape[0],), lambda s, i: (0,)),  # stationary
         ],
         out_specs=pl.BlockSpec((SEG_BLOCK,), lambda s, i: (s,)),
         out_shape=jax.ShapeDtypeStruct((s_pad,), jnp.float32),
         interpret=interpret,
-    )(src_p, dst_p, valid_p, x_f)
+    )(dst_p, vals_p)
     return out[:num_vertices]

@@ -34,10 +34,7 @@ from repro.launch.input_specs import (                         # noqa: E402
     cell_is_applicable,
     token_spec,
 )
-from repro.launch.mesh import (                                # noqa: E402
-    make_production_mesh,
-    mesh_context,
-)
+from repro.launch.mesh import make_production_mesh            # noqa: E402
 from repro.launch.sharding import (                            # noqa: E402
     act_sharding,
     batch_shardings,
@@ -77,7 +74,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool) -> Dict[str, Any]:
     mesh = make_production_mesh(multi_pod=multi_pod)
     n_chips = int(np.prod(list(mesh.shape.values())))
     t0 = time.time()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         aparams = abstract_params(cfg)
         p_sh = params_shardings(aparams, mesh, cfg)
         # seq-parallel for full-sequence shapes (the batched-q-block chunked

@@ -20,27 +20,6 @@ def make_host_mesh():
     return jax.make_mesh((1, 1), ("data", "model"))
 
 
-def mesh_context(mesh):
-    """Ambient-mesh context manager across JAX versions.
-
-    ``jax.set_mesh`` (newer releases) / ``jax.sharding.use_mesh``
-    (transitional) when available; otherwise the :class:`Mesh` itself,
-    which is a context manager on older lines (0.4.x).  Usage::
-
-        with mesh_context(mesh):
-            ...
-    """
-    import jax.sharding
-
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
-    if use_mesh is not None:
-        return use_mesh(mesh)
-    return mesh
-
-
 def dp_axes(mesh) -> tuple:
     """The data-parallel axes of a mesh (pod axis folds into DP)."""
     names = mesh.axis_names

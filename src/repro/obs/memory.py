@@ -97,21 +97,16 @@ def device_memory_stats(gauges: bool = True) -> Dict[str, Dict[str, int]]:
     """Live/peak/limit HBM bytes per device, mirrored into gauges.
 
     Returns ``{device: {"in_use": n, "peak": n, "limit": n}}`` with only
-    the kinds the backend reports.  CPU backends expose no
-    ``memory_stats`` — the result is ``{}`` and nothing is gauged, so the
-    call is safe to make unconditionally from ``cache_info()``.
+    the kinds the backend reports.  CPU backends report no
+    ``memory_stats`` (``None``) — the result is ``{}`` and nothing is
+    gauged, so the call is safe to make unconditionally from
+    ``cache_info()``.  A backend that fails to report raises.
     """
-    try:
-        import jax
-        devices = jax.local_devices()
-    except Exception:
-        return {}
+    import jax
+
     out: Dict[str, Dict[str, int]] = {}
-    for d in devices:
-        try:
-            stats = d.memory_stats()
-        except Exception:
-            stats = None
+    for d in jax.local_devices():
+        stats = d.memory_stats()
         if not stats:
             continue
         rec: Dict[str, int] = {}

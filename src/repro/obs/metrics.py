@@ -357,6 +357,9 @@ FAILURE_FAMILIES: Dict[str, str] = {
         "Warm restarts recovered from a durable_dir, by path.",
     "durability_faults_injected_total":
         "Faults fired by the injection harness, by site and action.",
+    "pipeline_reoptimization_failures_total":
+        "Background full-optimization recompiles that raised (the opt-0 "
+        "executable kept serving).",
 }
 
 

@@ -7,13 +7,9 @@ shard-local sort-merge join.  JS-MV composes with this naturally — a
 materialized view is stored already partitioned by its key, so every reuse
 skips the repartition (the distributed version of "materialize once").
 
-The optional Bloom prefilter (kernels/bloom.py) drops probe rows that
-cannot match *before* the exchange, cutting the all_to_all payload — the
-collective-term optimization recorded in EXPERIMENTS.md §Perf.
-
 Row routing: dest shard = key % n_shards; per-destination capacity is
-static (2x fair share by default) with drop-free guarantees asserted by the
-caller via :func:`exchange_overflow` (counts, exact).
+static (2x fair share by default); :func:`repartition_by_key` returns the
+exact count of rows it dropped, for the caller to check.
 """
 from __future__ import annotations
 
@@ -23,11 +19,6 @@ from typing import Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as PS
-
-try:  # jax>=0.6 moved shard_map
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # pragma: no cover
-    from jax import shard_map
 
 from repro.relational.join import sort_merge_join
 from repro.relational.table import Table
@@ -85,8 +76,8 @@ def repartition_by_key(table: Table, key_col: str, mesh, axis: str = "data",
     specs_in = ({k: PS(axis, None) for k in cols2d}, PS(axis, None))
     specs_out = ({k: PS(axis, None) for k in cols2d}, PS(axis, None),
                  PS(axis))
-    fn = shard_map(body, mesh=mesh, in_specs=specs_in,
-                   out_specs=specs_out, check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=specs_in,
+                   out_specs=specs_out, check_vma=False)
     out_cols, out_valid, overflow = fn(cols2d, valid2d)
     out = Table(columns={k: v.reshape(-1) for k, v in out_cols.items()},
                 valid=out_valid.reshape(-1))
@@ -130,9 +121,9 @@ def distributed_join(
                 {k: PS(axis, None) for k in rcols}, PS(axis, None))
     out_cols_spec = {k: PS(axis, None)
                      for k in list(lcols) + list(rcols)}
-    fn = shard_map(body, mesh=mesh, in_specs=specs_in,
+    fn = jax.shard_map(body, mesh=mesh, in_specs=specs_in,
                    out_specs=(out_cols_spec, PS(axis, None)),
-                   check_rep=False)
+                   check_vma=False)
     out_cols, out_valid = fn(lcols, left.valid.reshape(n, lrows),
                              rcols, right.valid.reshape(n, rrows))
     return Table(columns={k: v.reshape(-1) for k, v in out_cols.items()},

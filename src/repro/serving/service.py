@@ -106,7 +106,6 @@ class GraphService:
                  tenant_quotas: Optional[Dict[str, TenantQuota]] = None,
                  keep_snapshots: int = 2,
                  refresh_threshold: float = 0.1,
-                 persistent_cache: Optional[str] = None,
                  engine_opts: Optional[Dict[str, int]] = None,
                  durable_dir: Optional[str] = None,
                  retry_attempts: int = 3):
@@ -124,14 +123,13 @@ class GraphService:
         if durable_dir is not None:
             db, base_db, base_engine = self._recover(
                 db, durable_dir, compiler=compiler, compiled=compiled,
-                refresh_threshold=refresh_threshold,
-                persistent_cache=persistent_cache)
+                refresh_threshold=refresh_threshold)
         else:
             base_db = db.snapshot()
             base_engine = ExtractionEngine(
                 base_db, compiler=compiler, compiled=compiled,
                 auto_refresh=False, refresh_threshold=refresh_threshold,
-                persistent_cache=persistent_cache, **opts)
+                **opts)
         self._db = db
         self.compiler = base_engine.compiler
         self._store = SnapshotStore(
@@ -144,7 +142,7 @@ class GraphService:
         self.started_at = time.time()
 
     def _recover(self, base: Database, durable_dir: str, *,
-                 compiler, compiled, refresh_threshold, persistent_cache):
+                 compiler, compiled, refresh_threshold):
         """Warm-restart path: manifest restore → verify → replay → re-arm.
 
         1. Restore the checkpointed database at the last published epoch P
@@ -179,7 +177,7 @@ class GraphService:
             engine = ExtractionEngine(
                 snap_db, compiler=compiler, compiled=compiled,
                 auto_refresh=False, refresh_threshold=refresh_threshold,
-                persistent_cache=persistent_cache, **self._engine_opts)
+                **self._engine_opts)
         else:
             db = _recovery.restore_database(durable_dir, manifest)
             path, manifest_epoch = "checkpoint", int(manifest["epoch"])
@@ -190,7 +188,7 @@ class GraphService:
             engine = ExtractionEngine(
                 db_at_p, compiler=compiler, compiled=compiled,
                 auto_refresh=False, refresh_threshold=refresh_threshold,
-                persistent_cache=persistent_cache, **self._engine_opts)
+                **self._engine_opts)
             digests = dict(manifest.get("graph_digests") or {})
             graphs = _recovery.load_graphs(durable_dir, manifest)
             for name in sorted(digests):

@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.launch.mesh import mesh_context
 from repro.relational import Table, sort_merge_join
 from repro.relational.distributed import distributed_join
 
@@ -33,7 +32,7 @@ right = Table.from_arrays(
     b=np.arange(NR, dtype=np.int32)).prefix("R")
 
 oracle = sort_merge_join(left, right, on=[("L.k", "R.k")])
-with mesh_context(mesh):
+with jax.set_mesh(mesh):
     got = distributed_join(left, right, on=[("L.k", "R.k")], mesh=mesh,
                            capacity_per_shard=1 << 13)
 want = oracle.to_rowset(["L.a", "R.b"])

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_smoke_config
-from repro.launch.mesh import mesh_context
 from repro.launch.sharding import (act_sharding, batch_shardings,
                                    cache_shardings, params_shardings)
 from repro.launch.input_specs import cache_specs, token_spec
@@ -34,7 +33,7 @@ mesh = jax.make_mesh((2, 4), ("data", "model"))
 results = {}
 for arch in ["qwen2.5-3b", "qwen3-moe-235b-a22b"]:
     cfg = get_smoke_config(arch)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params = init_params(cfg, jax.random.PRNGKey(0))
         p_sh = params_shardings(jax.eval_shape(lambda: params), mesh, cfg)
         params = jax.tree_util.tree_map(jax.device_put, params, p_sh)

@@ -164,6 +164,16 @@ def test_csr_offsets_kernel_path():
     np.testing.assert_array_equal(np.asarray(off_k), np.asarray(off_j))
 
 
+@pytest.mark.parametrize("on_tpu", [False, True])
+def test_kernel_policy_keeps_probe_off_by_default(monkeypatch, on_tpu):
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_on_tpu", lambda: on_tpu)
+    assert ops.resolve_use_kernel(None) is on_tpu
+    assert ops.resolve_use_kernel(None, probe=True) is False
+    assert ops.resolve_use_kernel(True, probe=True) is True
+    assert ops.resolve_use_kernel(False) is False
+
+
 @pytest.mark.parametrize("sq,sk,hq,hkv,dh", [
     (128, 128, 2, 1, 64),     # MQA, padded head_dim
     (256, 256, 4, 2, 128),    # GQA, aligned

@@ -102,7 +102,7 @@ def test_kernel_probe_and_bloom_parity(tpcds_db):
     plan = plan_queries(tpcds_db.snapshot(), model.queries(), "extgraph")
     eager = run_plan(tpcds_db.snapshot(), plan)[0]
     comp = PipelineCompiler(use_kernel=True, use_bloom=True)
-    assert comp.use_kernel and comp.use_bloom
+    assert comp.use_kernel and comp.use_bloom and comp.probe_kernel
     compiled = run_plan(tpcds_db.snapshot(), plan, compiler=comp)[0]
     assert _digests(compiled) == _digests(eager)
 
@@ -163,3 +163,13 @@ def test_vertices_ride_along_compiled(tpcds_db):
         data = t.to_numpy()
         assert data["src"].dtype == np.int32
         assert (data["src"] >= 0).all() and (data["dst"] >= 0).all()
+
+
+def test_failed_background_recompile_is_counted():
+    from repro import obs
+    from repro.core import pipeline
+
+    before = obs.REGISTRY.value(pipeline.REOPT_FAILURES)
+    pipeline._submit_reopt(lambda: 1 // 0)
+    pipeline.drain_reoptimizations()
+    assert obs.REGISTRY.value(pipeline.REOPT_FAILURES) == before + 1

@@ -617,35 +617,38 @@ def test_http_quota_returns_429_with_retry_after(http_server):
 
 
 # ---------------------------------------------------------------------------
-# persistent compilation cache (opt-in flag; subprocess keeps jax config
-# of the test process untouched)
+# persistent compilation cache: JAX_COMPILATION_CACHE_DIR wins, else the
+# checkout's fixed directory (subprocess keeps jax config of the test
+# process untouched)
 # ---------------------------------------------------------------------------
 
 def test_persistent_compilation_cache_flag(tmp_path):
     code = textwrap.dedent("""
-        import jax, sys
+        import jax, os, sys
         from repro.core.pipeline import (
+            DEFAULT_CACHE_DIR,
             enable_persistent_compilation_cache,
             persistent_compilation_cache_dir,
         )
-        dir_a, dir_b = sys.argv[1], sys.argv[2]
+        dir_a = sys.argv[1]
+        fixed = os.path.normpath(os.path.abspath(DEFAULT_CACHE_DIR))
+        assert fixed == os.path.join(os.getcwd(), ".jax_cache"), fixed
         assert persistent_compilation_cache_dir() is None
-        assert enable_persistent_compilation_cache(None) is None  # opt-in
+        assert enable_persistent_compilation_cache() == fixed
+        assert persistent_compilation_cache_dir() == fixed
+        assert jax.config.jax_compilation_cache_dir == fixed
+        assert enable_persistent_compilation_cache() == fixed  # idem
+        # an explicit path (the cold-cache benchmark) repoints ...
         assert enable_persistent_compilation_cache(dir_a) == dir_a
-        assert persistent_compilation_cache_dir() == dir_a
         assert jax.config.jax_compilation_cache_dir == dir_a
-        assert enable_persistent_compilation_cache(dir_a) == dir_a  # idem
-        assert enable_persistent_compilation_cache(None) is None
-        assert persistent_compilation_cache_dir() == dir_a  # unchanged
-        assert enable_persistent_compilation_cache(dir_b) == dir_b  # repoint
-        assert jax.config.jax_compilation_cache_dir == dir_b
+        # ... and a later engine start-up keeps it
+        assert enable_persistent_compilation_cache() == dir_a
         print("OK")
     """)
     env = {**os.environ, "PYTHONPATH": "src"}
-    env.pop("REPRO_COMPILATION_CACHE", None)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     out = subprocess.run(
-        [sys.executable, "-c", code,
-         str(tmp_path / "cache_a"), str(tmp_path / "cache_b")],
+        [sys.executable, "-c", code, str(tmp_path / "cache_a")],
         capture_output=True, text=True, env=env)
     assert out.returncode == 0, out.stderr
     assert "OK" in out.stdout
@@ -654,23 +657,32 @@ def test_persistent_compilation_cache_flag(tmp_path):
 
 def test_persistent_cache_env_var_reaches_engine(tmp_path):
     code = textwrap.dedent("""
+        import jax, os, sys
         from repro.api.engine import ExtractionEngine
         from repro.core.database import Database
-        from repro.core.pipeline import persistent_compilation_cache_dir
-        import os
+        from repro.core.pipeline import (
+            enable_persistent_compilation_cache,
+            persistent_compilation_cache_dir,
+        )
+        env_dir = os.environ["JAX_COMPILATION_CACHE_DIR"]
         assert persistent_compilation_cache_dir() is None
-        ExtractionEngine(Database({}))       # ctor picks up the env var
-        assert persistent_compilation_cache_dir() == \
-            os.environ["REPRO_COMPILATION_CACHE"]
+        ExtractionEngine(Database({}))       # start-up enables the cache
+        assert persistent_compilation_cache_dir() == env_dir
+        assert jax.config.jax_compilation_cache_dir == env_dir
+        # no explicit path overrides the environment variable
+        assert enable_persistent_compilation_cache(sys.argv[1]) == env_dir
+        assert jax.config.jax_compilation_cache_dir == env_dir
         print("OK")
     """)
     cache_dir = str(tmp_path / "env_cache")
     out = subprocess.run(
-        [sys.executable, "-c", code], capture_output=True, text=True,
+        [sys.executable, "-c", code, str(tmp_path / "other")],
+        capture_output=True, text=True,
         env={**os.environ, "PYTHONPATH": "src",
-             "REPRO_COMPILATION_CACHE": cache_dir})
+             "JAX_COMPILATION_CACHE_DIR": cache_dir})
     assert out.returncode == 0, out.stderr
     assert "OK" in out.stdout
+    assert not (tmp_path / "other").exists()
 
 
 # ---------------------------------------------------------------------------
