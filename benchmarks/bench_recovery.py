@@ -7,9 +7,9 @@ without a clean shutdown.  Two recovery strategies are then timed against
 the same final table contents:
 
 * ``cold_s`` — rebuild from scratch: fresh engine, fresh compiler, XLA
-  executable caches cleared, persistent compilation cache pointed at an
-  *empty* directory.  This is the bill a restart pays without the
-  checkpoint + WAL + compile cache the subsystem persists.
+  executable caches cleared, persistent compilation cache turned off.
+  This is the bill a restart pays without the checkpoint + WAL + compile
+  cache the subsystem persists.
 * ``restart_to_warm_s`` — the crash-recovery path: a new ``GraphService``
   over the same ``durable_dir`` (manifest restore → digest verification →
   WAL-tail replay) with the persistent compilation cache the dead
@@ -46,7 +46,7 @@ from repro.core.pipeline import (
     PipelineCompiler,
     clear_executable_cache,
     drain_reoptimizations,
-    enable_persistent_compilation_cache,
+    persistent_compilation_cache_off,
 )
 from repro.data import fraud_model, make_tpcds
 from repro.serving import GraphService
@@ -117,26 +117,24 @@ def run() -> List[Row]:
         workdir = tempfile.mkdtemp(prefix="bench_recovery_")
         try:
             durable = os.path.join(workdir, "durable")
-            # the compile cache the doomed process fills: the engine's
-            # default one (JAX_COMPILATION_CACHE_DIR, else the checkout's)
-            warm_cc = enable_persistent_compilation_cache()
+            # the doomed process fills the engines' compile cache, which
+            # the restart below finds again
             model, final_tables, ref_p, ref_final = _crash_durable_service(
                 sf, durable, rng)
 
             best_cold, best_restart, best_bd, replayed = (
                 float("inf"), float("inf"), {}, 0)
             for rep in range(REPEATS):
-                # Cold start: nothing survives — fresh compiler, empty
+                # Cold start: nothing survives — fresh compiler, no
                 # persistent compile cache, full extract over the tables.
                 clear_executable_cache()
                 drain_reoptimizations()
-                cold_cc = os.path.join(workdir, f"cc_cold_{rep}")
-                enable_persistent_compilation_cache(cold_cc)
                 cold_db = Database(dict(final_tables))
-                t0 = time.perf_counter()
-                cold_res = ExtractionEngine(
-                    cold_db, compiler=PipelineCompiler()).extract(model)
-                cold_s = time.perf_counter() - t0
+                with persistent_compilation_cache_off():
+                    t0 = time.perf_counter()
+                    cold_res = ExtractionEngine(
+                        cold_db, compiler=PipelineCompiler()).extract(model)
+                    cold_s = time.perf_counter() - t0
                 assert cold_res.graph.fingerprint() == ref_final
 
                 # Warm restart: recover from the durable dir + the compile
@@ -148,8 +146,6 @@ def run() -> List[Row]:
                 drain_reoptimizations()
                 durable_rep = os.path.join(workdir, f"durable_{rep}")
                 shutil.copytree(durable, durable_rep)
-
-                enable_persistent_compilation_cache(warm_cc)
 
                 def _restart(durable_rep=durable_rep):
                     svc = GraphService(Database(), {MODEL_NAME: model},

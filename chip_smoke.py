@@ -135,8 +135,8 @@ def _analytics(engine, model, use_kernel):
                         use_kernel=use_kernel)
     n = pr.csr.num_vertices
     src, dst, valid = _host_coo(pr.csr)
-    print(f"   V={n} E={int(valid.sum())} pagerank {pr.timings.analyze_s:.3f}s",
-          flush=True)
+    print(f"   V={n} E={int(valid.sum())} "
+          f"pagerank {pr.timings.analyze_s:.3f}s", flush=True)
     want = reference.pagerank_np(src, dst, valid, n, iters=PAGERANK_ITERS)
     got = np.asarray(pr.values)
     err = float(np.max(np.abs(got - want)))
@@ -165,30 +165,13 @@ def _analytics(engine, model, use_kernel):
     check(runs > 0, f'graph_algorithm_runs_total{{kernel="pallas"}} = {runs}')
 
 
-def _churn(svc, fact: str, frac: float, rng) -> int:
-    """Mixed insert/delete batch of ``frac`` of the fact rows."""
-    db = svc._db
-    k = max(1, int(db.stats[fact].rows * frac / 2))
-    base = int(np.asarray(db.tables[fact]["rid"]).max()) + 1
-    svc.mutate(fact, insert=dict(
-        rid=np.arange(base, base + k, dtype=np.int32),
-        c_sk=rng.integers(0, db.stats["customer"].rows, k).astype(np.int32),
-        i_sk=rng.integers(0, db.stats["item"].rows, k).astype(np.int32),
-        p_sk=rng.integers(0, db.stats["promotion"].rows, k).astype(np.int32),
-        o_sk=rng.integers(0, db.stats["outlet_store"].rows,
-                          k).astype(np.int32)))
-    live = np.flatnonzero(np.asarray(db.tables[fact].valid))
-    mask = np.zeros(db.tables[fact].capacity, dtype=bool)
-    mask[rng.choice(live, k, replace=False)] = True
-    svc.mutate(fact, delete_mask=mask)
-    return 2 * k
-
-
 def _served(db, model, use_kernel):
     from repro.api import ExtractionEngine
     from repro.core.database import Database
     from repro.core.pipeline import PipelineCompiler
     from repro.serving import GraphService
+
+    from benchmarks.trajectory import _churn
 
     name = "fraud_store"
     svc = GraphService(db, {name: model},
@@ -196,7 +179,7 @@ def _served(db, model, use_kernel):
                        max_workers=2)
     try:
         fp0 = svc.extract(name)["fingerprint"]
-        changed = _churn(svc, "store_sales", CHURN, np.random.default_rng(7))
+        changed = _churn(svc, np.random.default_rng(7), CHURN)
         t0 = time.perf_counter()
         out = svc.refresh()
         print(f"   refresh of {changed} changed rows "
@@ -263,7 +246,8 @@ def main(tpcds_sf: int = TPCDS_SF, dblp_scale: int = DBLP_SCALE,
               flush=True)
         model = fraud_model("store")
         engine, _ = _extract_pair(db, model, use_kernel)
-    print(f"   compile cache {persistent_compilation_cache_dir()}", flush=True)
+    print(f"   compile cache {persistent_compilation_cache_dir()}",
+          flush=True)
     with Phase("a: tpcds fraud analyze"):
         _analytics(engine, model, use_kernel)
     with Phase("b: dblp extract"):

@@ -35,6 +35,7 @@ set of valid rows.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import logging
 import os
@@ -110,38 +111,51 @@ _PERSISTENT_CACHE_LOCK = threading.Lock()
 log = logging.getLogger("repro.pipeline")
 
 
-def enable_persistent_compilation_cache(path: Optional[str] = None) -> str:
+def enable_persistent_compilation_cache() -> str:
     """Turn on JAX's persistent compilation cache; returns its directory.
 
     The directory is ``JAX_COMPILATION_CACHE_DIR`` when that is set, else
-    :data:`DEFAULT_CACHE_DIR`.  Without ``path`` a cache already enabled
-    stays where it is.  ``path`` replaces only the default (the environment
-    variable still wins); it is for a benchmark that measures a cold-cache
-    restart on purpose.  Thresholds are lowered so even small unit
-    executables are persisted.
+    :data:`DEFAULT_CACHE_DIR`.  Idempotent.  Thresholds are lowered so even
+    small unit executables are persisted.
     """
     global _PERSISTENT_CACHE_DIR
     with _PERSISTENT_CACHE_LOCK:
-        if path is None and _PERSISTENT_CACHE_DIR is not None:
-            return _PERSISTENT_CACHE_DIR
-        path = (os.environ.get(CACHE_DIR_ENV) or os.path.normpath(
-            os.path.abspath(path or DEFAULT_CACHE_DIR)))
-        if _PERSISTENT_CACHE_DIR == path:
-            return path
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        # a cache JAX already opened keeps its directory until reset
-        compilation_cache.reset_cache()
-        _PERSISTENT_CACHE_DIR = path
-    return path
+        if _PERSISTENT_CACHE_DIR is None:
+            path = (os.environ.get(CACHE_DIR_ENV)
+                    or os.path.normpath(DEFAULT_CACHE_DIR))
+            os.makedirs(path, exist_ok=True)
+            jax.config.update("jax_compilation_cache_dir", path)
+            jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                              0.0)
+            jax.config.update("jax_persistent_cache_min_entry_size_bytes",
+                              -1)
+            # a cache JAX already opened keeps its directory until reset
+            compilation_cache.reset_cache()
+            _PERSISTENT_CACHE_DIR = path
+        return _PERSISTENT_CACHE_DIR
 
 
 def persistent_compilation_cache_dir() -> Optional[str]:
     """The directory enabled via :func:`enable_persistent_compilation_cache`
     (``None`` until the first engine starts)."""
     return _PERSISTENT_CACHE_DIR
+
+
+@contextlib.contextmanager
+def persistent_compilation_cache_off():
+    """Compile without reading or writing the persistent cache.
+
+    For a measurement that must pay every compile (a cold start), and for
+    AOT compiles for a described chip, whose entries no process could load.
+    """
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
 
 
 REOPT_FAILURES = "pipeline_reoptimization_failures_total"
@@ -560,9 +574,10 @@ class PipelineCompiler:
     ``use_bloom`` (default: follows ``use_kernel``) prunes probe rows with
     the ``bloom`` semi-join prefilter kernel before each capacity
     expansion, and ``probe_kernel`` (on only when ``use_kernel=True``)
-    routes the join probe through ``sorted_probe``.  ``initial_capacity_clamp`` caps the *initial* capacity
-    buckets — production code never sets it; tests use it to force the
-    overflow-retry branch.
+    routes the join probe through ``sorted_probe``.
+    ``initial_capacity_clamp`` caps the *initial* capacity buckets —
+    production code never sets it; tests use it to force the overflow-retry
+    branch.
     """
 
     def __init__(self, margin: float = CAPACITY_MARGIN,

@@ -3,9 +3,10 @@
 Computes ``next[v] = (exists valid edge (u -> v) with frontier[u]) and not
 visited[v]`` — one BFS level.  TPU adaptation: reached-neighbor counting is
 the same one-hot matmul as ``spmv`` (frontier membership as a 0/1 value
-per edge, gathered in XLA before the call, contracted with the one-hot destination
-matrix on the MXU); the ``~visited`` filter is applied once, on the last
-edge tile, after the counts for this vertex block have fully accumulated.
+per edge, gathered in XLA before the call, contracted with the one-hot
+destination matrix on the MXU); the ``~visited`` filter is applied once,
+on the last edge tile, after the counts for this vertex block have fully
+accumulated.
 
 Grid = (vertices/SEG_BLOCK, edges/TILE), accumulate pattern with a
 finalization step — out holds raw reach-counts until the last input tile
@@ -45,7 +46,7 @@ def _frontier_kernel(dst_ref, front_ref, visited_ref, out_ref):
     @pl.when(inp_tile == pl.num_programs(1) - 1)
     def _finalize():
         reached = out_ref[...] > 0
-        out_ref[...] = (reached & (visited_ref[...] == 0)).astype(jnp.int32)
+        out_ref[...] = (reached & ~visited_ref[...]).astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("num_vertices", "interpret"))
@@ -60,9 +61,8 @@ def frontier_expand(src: jax.Array, dst: jax.Array, valid: jax.Array,
     dst_p, front_p, grid, s_pad = pad_coo(
         src, dst, valid, frontier.astype(jnp.float32), num_vertices, TILE,
         SEG_BLOCK, fill=0.0)
-    # int32, not bool: Mosaic loads no 1-D bool block
-    vis = jnp.pad(visited.astype(jnp.int32), (0, s_pad - num_vertices),
-                  constant_values=1)
+    vis = jnp.pad(visited.astype(bool), (0, s_pad - num_vertices),
+                  constant_values=True)
     out = pl.pallas_call(
         _frontier_kernel,
         grid=grid,

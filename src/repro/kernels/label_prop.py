@@ -7,8 +7,9 @@ and iterate to a fixed point for weakly connected components.
 TPU adaptation: the scatter-min over destination vertices is the masked-min
 variant of the one-hot idiom — each edge tile brings its source labels
 ``labels[src]`` (gathered in XLA before the call, see ``_coo_tiling``),
-builds the (TILE x SEG_BLOCK) one-hot destination mask, lifts non-members to +inf (INT32_MAX), and min-reduces over the edge
-axis on the VPU, folding into the output block across grid steps.  The
+builds the (TILE x SEG_BLOCK) one-hot destination mask, lifts non-members
+to +inf (INT32_MAX), and min-reduces over the edge axis on the VPU,
+folding into the output block across grid steps.  The
 output block is initialized from the vertex's own label so the identity
 ``new <= labels`` holds even for isolated vertices.
 

@@ -77,7 +77,7 @@ def repartition_by_key(table: Table, key_col: str, mesh, axis: str = "data",
     specs_out = ({k: PS(axis, None) for k in cols2d}, PS(axis, None),
                  PS(axis))
     fn = jax.shard_map(body, mesh=mesh, in_specs=specs_in,
-                   out_specs=specs_out, check_vma=False)
+                       out_specs=specs_out, check_vma=False)
     out_cols, out_valid, overflow = fn(cols2d, valid2d)
     out = Table(columns={k: v.reshape(-1) for k, v in out_cols.items()},
                 valid=out_valid.reshape(-1))
@@ -122,8 +122,8 @@ def distributed_join(
     out_cols_spec = {k: PS(axis, None)
                      for k in list(lcols) + list(rcols)}
     fn = jax.shard_map(body, mesh=mesh, in_specs=specs_in,
-                   out_specs=(out_cols_spec, PS(axis, None)),
-                   check_vma=False)
+                       out_specs=(out_cols_spec, PS(axis, None)),
+                       check_vma=False)
     out_cols, out_valid = fn(lcols, left.valid.reshape(n, lrows),
                              rcols, right.valid.reshape(n, rrows))
     return Table(columns={k: v.reshape(-1) for k, v in out_cols.items()},

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.pipeline import persistent_compilation_cache_off
 from repro.kernels import (
     bloom,
     frontier,
@@ -48,15 +49,8 @@ def topo():
 
 @pytest.fixture(scope="module")
 def one_chip(topo):
-    # a compile for a described chip is written to the persistent cache but
-    # cannot be read back without one: keep the cache off around them
-    from jax.experimental.compilation_cache import compilation_cache
-    was_enabled = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
-    jax.config.update("jax_enable_compilation_cache", was_enabled)
-    compilation_cache.reset_cache()
+    with persistent_compilation_cache_off():
+        yield SingleDeviceSharding(topo.devices[0])
 
 
 def _cases():
