@@ -753,7 +753,6 @@ class ExtractionEngine:
                 for label in edges:
                     jax.block_until_ready(edges[label].valid)
             timings.extract_s = time.perf_counter() - t0
-            self._harvest_views(rdb, plan, built, reused)
             provenance = PlanProvenance(
                 method=method, plan_cache_hit=hit,
                 views_built=tuple(built), views_reused=tuple(reused))
@@ -769,7 +768,11 @@ class ExtractionEngine:
             graph = ExtractedGraph(vertices=vertices, edges=edges)
             graph.block_until_ready()
         if method in PLANNED_METHODS:
-            self._remember_result(model, method, plan, graph, epoch0)
+            # host bookkeeping the next request reads: built views into
+            # the view cache, the result (and its plan key) into the memo
+            with obs.span("remember", category="plan"):
+                self._harvest_views(rdb, plan, built, reused)
+                self._remember_result(model, method, plan, graph, epoch0)
         return ExtractionResult(graph=graph, timings=timings,
                                 provenance=provenance, plan=plan,
                                 model=model, _engine=self)

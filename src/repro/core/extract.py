@@ -61,20 +61,27 @@ class ExtractedGraph:
         plan, or (cold vs incremental-refresh) path got them there — share
         a fingerprint, which is what lets the engine's CSR cache skip the
         rebuild.  Memoized: the tables are immutable, and the refresh path
-        digests each graph once to locate its patchable CSR.
+        digests each graph once to locate its patchable CSR.  Spans:
+        ``graph.fetch`` (the tables' valid rows copied to the host) and
+        ``graph.digest`` (host sort and hash).
         """
         if self._fp is not None:
             return self._fp
         import hashlib
 
-        from repro.relational.ops import table_digest
+        from repro.relational.ops import rows_digest
 
-        h = hashlib.sha1()
-        for kind, tables in (("v", self.vertices), ("e", self.edges)):
-            for label in sorted(tables):
-                h.update(f"{kind}:{label}:".encode())
-                h.update(table_digest(tables[label]).encode())
-        self._fp = h.hexdigest()[:16]
+        parts = [(f"{kind}:{label}:", tables[label])
+                 for kind, tables in (("v", self.vertices), ("e", self.edges))
+                 for label in sorted(tables)]
+        with obs.span("graph.fetch", category="transfer"):
+            host = [(tag, table.to_numpy()) for tag, table in parts]
+        with obs.span("graph.digest", category="csr"):
+            h = hashlib.sha1()
+            for tag, data in host:
+                h.update(tag.encode())
+                h.update(rows_digest(data).encode())
+            self._fp = h.hexdigest()[:16]
         return self._fp
 
 

@@ -40,6 +40,7 @@ import dataclasses
 import logging
 import os
 import queue
+import re
 import threading
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -385,13 +386,14 @@ def _scan(tables: Dict[str, Table], rel, needed=None) -> Table:
     every column the rest of the unit never references — scan filters are
     applied first, so filter columns need not survive the projection.
     Fewer columns means fewer gathers per join step: less to compile, less
-    to move.
+    to move.  Runs under the ``scan:<alias>`` scope.
     """
-    t = scan_table(tables[rel.table], rel)
-    if needed is not None:
-        keep = [c for c in t.column_names() if c in needed]
-        if keep and len(keep) < len(t.columns):
-            t = t.select(keep)
+    with jax.named_scope(f"scan:{rel.alias}"):
+        t = scan_table(tables[rel.table], rel)
+        if needed is not None:
+            keep = [c for c in t.column_names() if c in needed]
+            if keep and len(keep) < len(t.columns):
+                t = t.select(keep)
     return t
 
 
@@ -427,6 +429,7 @@ def _traced_query(
     order: Sequence[str],
     caps_iter,
     totals: List[jax.Array],
+    prefilter: List[jax.Array],
     use_kernel: bool,
     use_bloom: bool,
     needed=None,
@@ -435,20 +438,25 @@ def _traced_query(
 
     Same schedule as :func:`executor.execute_query` — both walk
     :func:`repro.core.model.join_schedule`, which is what keeps the
-    pre-planned capacities aligned with the joins actually traced.
+    pre-planned capacities aligned with the joins actually traced.  Each
+    step runs under a ``jax.named_scope`` (``scan:<alias>``,
+    ``join:<alias>``), which the compiled HLO keeps in its ops' metadata.
     """
     cur = _scan(tables, query.relation(order[0]), needed)
     for alias, conds, closing in join_schedule(query, order):
         nxt = _scan(tables, query.relation(alias), needed)
         on = [qualified_cond(c, alias) for c in conds]
-        cur, required = join_with_capacity(
-            cur, nxt, on, how="inner", capacity=next(caps_iter),
-            use_kernel=use_kernel,
-            bloom_bits=bloom_bits_for(nxt.capacity) if use_bloom else 0)
+        with jax.named_scope(f"join:{alias}"):
+            cur, required, counts = join_with_capacity(
+                cur, nxt, on, how="inner", capacity=next(caps_iter),
+                use_kernel=use_kernel,
+                bloom_bits=bloom_bits_for(nxt.capacity) if use_bloom else 0)
+            for c in closing:
+                cur = cur.mask(cur[f"{c.left}.{c.lcol}"]
+                               == cur[f"{c.right}.{c.rcol}"])
         totals.append(required)
-        for c in closing:
-            cur = cur.mask(cur[f"{c.left}.{c.lcol}"]
-                           == cur[f"{c.right}.{c.rcol}"])
+        if counts is not None:
+            prefilter.append(counts)
     return cur
 
 
@@ -458,13 +466,19 @@ def _traced_merged(
     orders: Sequence[Tuple[str, ...]],
     caps_iter,
     totals: List[jax.Array],
+    prefilter: List[jax.Array],
     use_kernel: bool,
     use_bloom: bool,
 ) -> Dict[str, Table]:
-    """The executor's JS-OJ evaluation (Theorem 4.3), fully traced."""
+    """The executor's JS-OJ evaluation (Theorem 4.3), fully traced.
+
+    Scopes as in :func:`_traced_query`, plus ``outer:<branch>`` for each
+    branch's outer-join attachment and ``dedup:<member>`` for each
+    member's row filter, dedup and edge projection.
+    """
     needed = _needed_columns_merged(merged)
     cur = _traced_query(tables, shared_query(merged), orders[0], caps_iter,
-                        totals, use_kernel, use_bloom, needed)
+                        totals, prefilter, use_kernel, use_bloom, needed)
     cur = cur.with_columns(
         __srow__=jnp.arange(cur.capacity, dtype=jnp.int32))
     indicators: Dict[str, str] = {}
@@ -473,62 +487,131 @@ def _traced_merged(
         ind = f"__m__{b.id}"
         indicators[b.id] = ind
         if not b.relations:
-            mask = jnp.ones((cur.capacity,), dtype=bool)
-            for c in b.link_conds:
-                mask = mask & (cur[f"{c.left}.{c.lcol}"]
-                               == cur[f"{c.right}.{c.rcol}"])
-            cur = cur.with_columns(**{ind: mask})
+            with jax.named_scope(f"outer:{b.id}"):
+                mask = jnp.ones((cur.capacity,), dtype=bool)
+                for c in b.link_conds:
+                    mask = mask & (cur[f"{c.left}.{c.lcol}"]
+                                   == cur[f"{c.right}.{c.rcol}"])
+                cur = cur.with_columns(**{ind: mask})
             continue
         if len(b.relations) > 1:
             branch_tbl = _traced_query(tables, b.as_query(), orders[1 + bi],
-                                       caps_iter, totals, use_kernel,
-                                       use_bloom, needed)
+                                       caps_iter, totals, prefilter,
+                                       use_kernel, use_bloom, needed)
         else:
             branch_tbl = _scan(tables, b.relations[0], needed)
-        brow = f"__brow__{b.id}"
-        rowid_cols[b.id] = brow
-        branch_tbl = branch_tbl.with_columns(
-            **{brow: jnp.arange(branch_tbl.capacity, dtype=jnp.int32)})
-        on = [(f"{c.left}.{c.lcol}", f"{c.right}.{c.rcol}")
-              for c in b.link_conds]
-        cur, required = left_outer_with_capacity(
-            cur, branch_tbl, on, ind, capacity=next(caps_iter),
-            use_kernel=use_kernel,
-            bloom_bits=bloom_bits_for(branch_tbl.capacity)
-            if use_bloom else 0)
+        with jax.named_scope(f"outer:{b.id}"):
+            brow = f"__brow__{b.id}"
+            rowid_cols[b.id] = brow
+            branch_tbl = branch_tbl.with_columns(
+                **{brow: jnp.arange(branch_tbl.capacity, dtype=jnp.int32)})
+            on = [(f"{c.left}.{c.lcol}", f"{c.right}.{c.rcol}")
+                  for c in b.link_conds]
+            cur, required, counts = left_outer_with_capacity(
+                cur, branch_tbl, on, ind, capacity=next(caps_iter),
+                use_kernel=use_kernel,
+                bloom_bits=bloom_bits_for(branch_tbl.capacity)
+                if use_bloom else 0)
         totals.append(required)
+        if counts is not None:
+            prefilter.append(counts)
 
     out: Dict[str, Table] = {}
     for m in merged.members:
-        keep = jnp.ones((cur.capacity,), dtype=bool)
-        for bid in m.branch_ids:
-            keep = keep & cur[indicators[bid]]
-        for c in m.residual_conds:
-            keep = keep & (cur[f"{c.left}.{c.lcol}"]
-                           == cur[f"{c.right}.{c.rcol}"])
-        member_rows = cur.mask(keep)
-        dedup_keys = ["__srow__"] + [
-            rowid_cols[bid] for bid in m.branch_ids if bid in rowid_cols
-        ]
-        member_rows = dedup(member_rows, dedup_keys)
-        out[m.name] = edge_output(member_rows, m.src, m.dst)
+        with jax.named_scope(f"dedup:{m.name}"):
+            keep = jnp.ones((cur.capacity,), dtype=bool)
+            for bid in m.branch_ids:
+                keep = keep & cur[indicators[bid]]
+            for c in m.residual_conds:
+                keep = keep & (cur[f"{c.left}.{c.lcol}"]
+                               == cur[f"{c.right}.{c.rcol}"])
+            member_rows = cur.mask(keep)
+            dedup_keys = ["__srow__"] + [
+                rowid_cols[bid] for bid in m.branch_ids if bid in rowid_cols
+            ]
+            member_rows = dedup(member_rows, dedup_keys)
+            out[m.name] = edge_output(member_rows, m.src, m.dst)
     return out
 
 
-def _stack_totals(totals: List[jax.Array]) -> jax.Array:
-    if not totals:
+def _stack_totals(totals: List[jax.Array],
+                  prefilter: List[jax.Array]) -> jax.Array:
+    """The one vector the host syncs per attempt: each join step's
+    required rows, then each Bloom prefilter's (probed, passed) pair."""
+    parts = [t.astype(jnp.int32).reshape(-1) for t in totals + prefilter]
+    if not parts:
         return jnp.zeros((0,), jnp.int32)
-    return jnp.stack([t.astype(jnp.int32) for t in totals])
+    return jnp.concatenate(parts)
+
+
+def _unit_function_name(program: UnitProgram) -> str:
+    """``unit_<label>``: the jitted function's name, so the unit's HLO
+    module is ``jit_unit_<label>``.  The label is the edge label, the
+    view's name, or the merged members' labels joined by ``_``."""
+    unit = program.unit
+    label = ("_".join(m.name for m in unit.members)
+             if program.kind == "merged" else unit.name)
+    return "unit_" + re.sub(r"[^0-9A-Za-z_]", "_", label)
+
+
+# the named scopes the traced steps run under (see _traced_merged)
+_STEP_SCOPES = ("scan:", "join:", "outer:", "dedup:")
+_INSTRUCTION = re.compile(r"\s*(?:ROOT\s+)?%([^\s=]+) = ")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def _step_scopes(hlo_text: str) -> Tuple[str, Dict[str, str]]:
+    """(module name, {instruction: step scope}) of one compiled text."""
+    module = hlo_text.split(None, 2)[1].rstrip(",")
+    scopes: Dict[str, str] = {}
+    for line in hlo_text.splitlines():
+        inst = _INSTRUCTION.match(line)
+        meta = inst and _OP_NAME.search(line)
+        if meta:
+            scope = next((seg for seg in meta.group(1).split("/")[1:]
+                          if seg.startswith(_STEP_SCOPES)), None)
+            if scope is not None:
+                scopes[inst.group(1)] = scope
+    return module, scopes
+
+
+def op_scopes() -> Dict[str, Dict[str, str]]:
+    """Plan step of each device op: ``{HLO module: {instruction: scope}}``.
+
+    Read from the compiled text of every cached unit executable (module
+    ``jit_unit_<label>``), whose ops keep the ``jax.named_scope`` of their
+    traced step in their ``op_name`` metadata.  A profile names its device
+    ops by module and instruction, so this maps each to ``join:<alias>``,
+    ``outer:<branch>``, ...  An instruction that two executables of one
+    module place under different scopes is left out.  Parses every text
+    on each call: for reading a profile, never on a request's path.
+    """
+    with _CACHE_LOCK:
+        executables = list(_EXECUTABLE_CACHE.values())
+    out: Dict[str, Dict[str, str]] = {}
+    clashes: Dict[str, set] = collections.defaultdict(set)
+    for exe in executables:
+        module, scopes = _step_scopes(exe.as_text())
+        known = out.setdefault(module, {})
+        for inst, scope in scopes.items():
+            if known.get(inst, scope) != scope:
+                clashes[module].add(inst)
+            known[inst] = scope
+    for module, insts in clashes.items():
+        for inst in insts:
+            del out[module][inst]
+    return out
 
 
 def _make_fn(program: UnitProgram, use_kernel: bool, use_bloom: bool):
     if program.kind == "merged":
         def fn(tables):
             totals: List[jax.Array] = []
+            prefilter: List[jax.Array] = []
             edges = _traced_merged(tables, program.unit, program.orders,
                                    iter(program.capacities), totals,
-                                   use_kernel, use_bloom)
-            return edges, _stack_totals(totals)
+                                   prefilter, use_kernel, use_bloom)
+            return edges, _stack_totals(totals, prefilter)
     else:
         # views ("query") keep every column — later queries are rewritten
         # over them and may reference any of it; edge units only carry what
@@ -538,12 +621,14 @@ def _make_fn(program: UnitProgram, use_kernel: bool, use_bloom: bool):
 
         def fn(tables):
             totals: List[jax.Array] = []
+            prefilter: List[jax.Array] = []
             res = _traced_query(tables, program.unit, program.orders[0],
-                                iter(program.capacities), totals,
+                                iter(program.capacities), totals, prefilter,
                                 use_kernel, use_bloom, needed)
             if program.kind == "edges":
                 res = edge_output(res, program.unit.src, program.unit.dst)
-            return res, _stack_totals(totals)
+            return res, _stack_totals(totals, prefilter)
+    fn.__name__ = fn.__qualname__ = _unit_function_name(program)
     return fn
 
 
@@ -557,6 +642,14 @@ def _schema_fp(inputs: Dict[str, Table]) -> Tuple:
         (name, t.capacity,
          tuple((c, str(t[c].dtype)) for c in t.column_names()))
         for name, t in inputs.items()))
+
+
+def _bloom_counter(outcome: str):
+    return obs.REGISTRY.counter(
+        "pipeline_bloom_rows_total",
+        help="Probe rows with a key before (probed) and after (passed) the "
+             "Bloom semi-join prefilter.",
+        outcome=outcome)
 
 
 class PipelineCompiler:
@@ -618,7 +711,13 @@ class PipelineCompiler:
         self.max_last_rows = 512
         self.stats = {"hits": 0, "misses": 0, "retries": 0,
                       "compiled": 0, "compile_s": 0.0,
-                      "tiered": 0, "reoptimized": 0}
+                      "tiered": 0, "reoptimized": 0,
+                      "bloom_probed": 0, "bloom_passed": 0}
+        # exported at 0 from the first compiler on, so a reader can tell a
+        # path without a prefilter (nothing probed) from a process that
+        # never ran a pipeline
+        for outcome in ("probed", "passed"):
+            _bloom_counter(outcome)
 
     _EVENT_METRIC = "pipeline_executable_events_total"
 
@@ -748,16 +847,10 @@ class PipelineCompiler:
             "pipeline_rows_estimate_ratio",
             help="Actual/predicted rows per join step (1 = perfect "
                  "cost-model estimate).", kind=prog.kind)
-        util_h = obs.REGISTRY.histogram(
-            "pipeline_capacity_utilization",
-            help="Actual rows / planned capacity per join step.",
-            kind=prog.kind)
         actual = [int(n) for n in need.tolist()]
         for i, n in enumerate(actual):
             if i < len(prog.est_rows):
                 ratio_h.observe((n + 1.0) / (prog.est_rows[i] + 1.0))
-            if i < len(caps) and caps[i] > 0:
-                util_h.observe(n / caps[i])
         with self._lock:
             self._last_rows[prog.signature] = {
                 "actual": actual,
@@ -767,6 +860,19 @@ class PipelineCompiler:
             self._last_rows.move_to_end(prog.signature)
             while len(self._last_rows) > self.max_last_rows:
                 self._last_rows.popitem(last=False)
+
+    def _count_prefilter(self, pairs: np.ndarray) -> None:
+        """Bloom prefilter rows of one kept attempt: probe rows with a key
+        before the filter (``probed``) and after it (``passed``), summed
+        over the unit's joins.  Host values from the one sync."""
+        if pairs.size == 0:
+            return
+        probed, passed = (int(x) for x in pairs.reshape(-1, 2).sum(axis=0))
+        with self._lock:
+            self.stats["bloom_probed"] += probed
+            self.stats["bloom_passed"] += passed
+        _bloom_counter("probed").inc(probed)
+        _bloom_counter("passed").inc(passed)
 
     def last_rows(self, signature) -> Optional[Dict[str, list]]:
         """Per-step ``{actual, capacities, est_rows}`` from the most recent
@@ -834,7 +940,9 @@ class PipelineCompiler:
         least doubles it; steps downstream of a truncation may only reveal
         their true requirement on the retry, so the loop runs to a fixpoint
         (bounded by the step count — each round fixes at least the first
-        overflowing step for good).
+        overflowing step for good).  The synced vector also carries each
+        Bloom prefilter's (probed, passed) rows, counted on the attempt
+        that is kept (:meth:`_count_prefilter`).
         """
         inputs = {n: db.tables[n] for n in prog.inputs}
         caps = prog.capacities
@@ -844,14 +952,17 @@ class PipelineCompiler:
         for _ in range(attempts + 1):
             cur = dataclasses.replace(prog, capacities=caps)
             exe = self._executable(cur, inputs)
-            with obs.span("pipeline.run", category="execute", detail=True,
-                          kind=prog.kind):
+            with obs.span("pipeline.dispatch", category="execute",
+                          detail=True, kind=prog.kind):
                 out, totals = exe(inputs)
-            with obs.span("pipeline.sync", category="transfer", detail=True):
-                need = np.asarray(totals)             # the one host sync
+            # the host only blocks here while the device runs the unit
+            with obs.span("pipeline.wait", category="execute", detail=True):
+                synced = np.asarray(totals)           # the one host sync
+            need, prefilter = synced[:len(caps)], synced[len(caps):]
             if need.size == 0 or bool(
                     (need <= np.asarray(caps, dtype=np.int64)).all()):
                 self._observe_rows(prog, caps, need)
+                self._count_prefilter(prefilter)
                 if caps != prog.capacities:
                     with self._lock:                  # skip retries next time
                         self._programs[pkey] = cur

@@ -88,6 +88,7 @@ def bloom_build(keys: jax.Array, valid: jax.Array, num_bits: int,
                                num_hashes=num_hashes)
     return pl.pallas_call(
         kernel,
+        name="bloom_build",
         grid=(num_bits // block, n_pad // TILE),
         in_specs=[
             pl.BlockSpec((TILE,), lambda b, i: (i,)),
@@ -113,6 +114,7 @@ def bloom_probe(bits: jax.Array, keys: jax.Array, num_hashes: int = 2,
                                num_hashes=num_hashes)
     out = pl.pallas_call(
         kernel,
+        name="bloom_probe",
         grid=(n_pad // TILE,),
         in_specs=[
             pl.BlockSpec(bits2d.shape, lambda i: (0, 0)),     # stationary

@@ -121,6 +121,7 @@ def flash_attention(
         nk=nk, scale=scale, sk_real=sk)
     out = pl.pallas_call(
         kernel,
+        name="flash_attention",
         grid=(b * hq, nq, nk),
         in_specs=[
             pl.BlockSpec((1, q_blk, dh_pad), lambda bh, qb, kb: (bh, qb, 0)),

@@ -65,6 +65,7 @@ def frontier_expand(src: jax.Array, dst: jax.Array, valid: jax.Array,
                   constant_values=True)
     out = pl.pallas_call(
         _frontier_kernel,
+        name="frontier_expand",
         grid=grid,
         in_specs=[
             pl.BlockSpec((TILE,), lambda s, i: (i,)),

@@ -61,6 +61,7 @@ def edge_min_label(src: jax.Array, dst: jax.Array, valid: jax.Array,
                   constant_values=_INT_MAX)
     out = pl.pallas_call(
         _minlabel_kernel,
+        name="edge_min_label",
         grid=grid,
         in_specs=[
             pl.BlockSpec((TILE,), lambda s, i: (i,)),

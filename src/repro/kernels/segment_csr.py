@@ -58,6 +58,7 @@ def segment_counts(values: jax.Array, valid: jax.Array, num_segments: int,
     grid = (s_pad // SEG_BLOCK, n_pad // TILE)
     out = pl.pallas_call(
         _hist_kernel,
+        name="segment_counts",
         grid=grid,
         in_specs=[
             pl.BlockSpec((TILE,), lambda s, i: (i,)),

@@ -66,6 +66,7 @@ def sorted_probe(sorted_keys: jax.Array, probe_keys: jax.Array,
                             (0, s_pad - n_sorted), constant_values=_INT_MAX)
     lo, hi = pl.pallas_call(
         _rank_kernel,
+        name="sorted_probe",
         grid=(p_pad // PROBE_BLOCK, s_pad // BUILD_BLOCK),
         in_specs=[
             pl.BlockSpec((BUILD_BLOCK,), lambda i, b: (b,)),

@@ -64,6 +64,7 @@ def edge_spmv(src: jax.Array, dst: jax.Array, valid: jax.Array,
         SEG_BLOCK, fill=0.0)
     out = pl.pallas_call(
         _spmv_kernel,
+        name="edge_spmv",
         grid=grid,
         in_specs=[
             pl.BlockSpec((TILE,), lambda s, i: (i,)),

@@ -19,12 +19,24 @@ Two kinds of spans:
   the compiled pipeline emit identical structural trees for the same model
   (only durations differ) — tested in ``tests/test_obs.py``.
 * **detail** spans (``detail=True``) attribute time inside a structural
-  span (per-unit ``pipeline.compile`` / ``pipeline.run`` /
-  ``pipeline.sync``, overflow retries).  They are excluded from shape
+  span (per-unit ``pipeline.compile`` / ``pipeline.dispatch`` /
+  ``pipeline.wait``, overflow retries).  They are excluded from shape
   comparison — the compiled path legitimately has more of them.
 
-Cost: a span is one ``perf_counter`` pair, a contextvar set/reset and one
-short-held lock on exit (~1-2 µs); with :func:`set_enabled` ``(False)``
+One naming rule: a span in which the host only blocks on the device ends
+in ``.wait`` (``pipeline.wait``).  Names starting with ``bench.`` are
+refused: the benchmark keeps its own profiler annotations by that prefix.
+
+**One clock with the device.**  While the tracer is enabled, every span
+opened with :func:`span` also opens a ``jax.profiler.TraceAnnotation`` of
+the same name, so a profiler trace (``jax.profiler.start_trace``) shows the
+program's spans on its host plane, on the profiler's clock, beside the
+device ops.  Spans recorded after the fact (:meth:`Tracer.record`: unit
+compiles, ``queue.wait``, coalesced followers) stay host-only.
+
+Cost: a span is one ``perf_counter`` pair, a contextvar set/reset, one
+profiler annotation (a no-op check while no profiler trace runs) and one
+short-held lock on exit (a few µs); with :func:`set_enabled` ``(False)``
 ``span()`` returns a shared no-op (< 1 µs).  No device syncs anywhere.
 The trace store is a ring: at most ``max_traces`` retained traces of at
 most ``max_spans`` spans each — an abandoned span flood cannot OOM a
@@ -45,11 +57,26 @@ import time
 import uuid
 from typing import Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 #: attribution categories a span may carry ("" -> other)
 CATEGORIES = ("plan", "compile", "execute", "transfer", "csr", "queue")
 
+#: span-name prefix kept for the benchmark's own profiler annotations
+RESERVED_PREFIX = "bench."
+
+#: the one naming rule for waits: a span in which the host only blocks on
+#: the device ends in this suffix (``pipeline.wait``)
+WAIT_SUFFIX = ".wait"
+
 _CTX: contextvars.ContextVar[Optional[Tuple[str, str]]] = \
     contextvars.ContextVar("repro_obs_span", default=None)
+
+
+def _check_name(name: str) -> None:
+    if name.startswith(RESERVED_PREFIX):
+        raise ValueError(f"span name {name!r}: the prefix "
+                         f"{RESERVED_PREFIX!r} is the benchmark's")
 
 
 def new_trace_id() -> str:
@@ -89,7 +116,7 @@ class _Span:
 
     __slots__ = ("_tracer", "name", "category", "detail", "attrs",
                  "trace_id", "span_id", "parent_id", "_start", "_token",
-                 "_thread")
+                 "_thread", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, category: str,
                  detail: bool, trace_id: Optional[str],
@@ -109,15 +136,18 @@ class _Span:
         self._start = time.perf_counter() if start_s is None else start_s
         self._thread = threading.get_ident()
         self._token = _CTX.set((self.trace_id, self.span_id))
+        self._annotation = TraceAnnotation(name)
 
     def set(self, **attrs) -> "_Span":
         self.attrs.update(attrs)
         return self
 
     def __enter__(self) -> "_Span":
+        self._annotation.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        self._annotation.__exit__(exc_type, exc, tb)
         _CTX.reset(self._token)
         if exc_type is not None:
             self.attrs["error"] = exc_type.__name__
@@ -157,6 +187,7 @@ class Tracer:
         ``start_s`` backdates the span (e.g. to a request's submit time)."""
         if not self.enabled:
             return _NOOP
+        _check_name(name)
         return _Span(self, name, category, detail, trace_id, start_s, attrs)
 
     def record(self, name: str, start_s: float, end_s: float,
@@ -168,6 +199,7 @@ class Tracer:
         current span is the parent)."""
         if not self.enabled:
             return
+        _check_name(name)
         if trace_id is None or parent_id is None:
             cur = _CTX.get()
             if trace_id is None:

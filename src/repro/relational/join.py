@@ -183,20 +183,22 @@ def join_with_capacity(
     indicator: Optional[str] = None,
     use_kernel: bool = False,
     bloom_bits: int = 0,
-) -> Tuple[Table, jax.Array]:
-    """Fully-traced join at a static capacity; returns (table, required).
+) -> Tuple[Table, jax.Array, Optional[jax.Array]]:
+    """Fully-traced join at a static capacity.
 
-    The building block of the compiled pipeline executor
-    (:mod:`repro.core.pipeline`): no host syncs, no data-dependent shapes.
-    ``required`` is the traced exact number of output slots the first-key
-    expansion needed; if it exceeds ``capacity`` the output was truncated
-    and the caller must re-execute at a larger capacity (the pipeline's
-    overflow-retry).  ``use_kernel`` routes the probe phase through the
-    Pallas ``sorted_probe`` kernel; ``bloom_bits > 0`` additionally prunes
-    probe rows through a Bloom-filter semi-join *before* the capacity
-    expansion.  Bloom filters have no false negatives, so pruning is exact
-    for inner joins and turns outer-join prunees into (correct) unmatched
-    null rows.
+    Returns ``(table, required, prefilter)``.  The building block of the
+    compiled pipeline executor (:mod:`repro.core.pipeline`): no host syncs,
+    no data-dependent shapes.  ``required`` is the traced exact number of
+    output slots the first-key expansion needed; if it exceeds ``capacity``
+    the output was truncated and the caller must re-execute at a larger
+    capacity (the pipeline's overflow-retry).  ``use_kernel`` routes the
+    probe phase through the Pallas ``sorted_probe`` kernel; ``bloom_bits >
+    0`` additionally prunes probe rows through a Bloom-filter semi-join
+    *before* the capacity expansion.  Bloom filters have no false
+    negatives, so pruning is exact for inner joins and turns outer-join
+    prunees into (correct) unmatched null rows.  ``prefilter`` is then the
+    int32 pair (probe rows with a key before the filter, those that
+    passed it); ``None`` without a prefilter.
     """
     on = list(on)
     key_on, rest = on[:1], on[1:]
@@ -204,12 +206,16 @@ def join_with_capacity(
     on_right = tuple(r for _, r in key_on)
     lk = composite_key(left, on_left)
     rk = composite_key(right, on_right)
+    prefilter = None
     if bloom_bits:
         from repro.kernels import ops as kops
 
         bits = kops.bloom_build(rk, right.valid & (rk != NULL_KEY64),
                                 bloom_bits)
+        probed = jnp.sum(lk != NULL_KEY64, dtype=jnp.int32)
         lk = jnp.where(kops.bloom_probe(bits, lk), lk, NULL_KEY64)
+        prefilter = jnp.stack([probed,
+                               jnp.sum(lk != NULL_KEY64, dtype=jnp.int32)])
     out, total = _join_core(left, right, lk, rk, how, capacity, indicator,
                             use_kernel)
     for lcol, rcol in rest:
@@ -219,7 +225,7 @@ def join_with_capacity(
             out = out.with_columns(**{indicator: out[indicator] & keep})
         else:
             out = out.mask(keep)
-    return out, total
+    return out, total, prefilter
 
 
 def left_outer_with_capacity(
@@ -230,8 +236,9 @@ def left_outer_with_capacity(
     capacity: int,
     use_kernel: bool = False,
     bloom_bits: int = 0,
-) -> Tuple[Table, jax.Array]:
-    """Traced exact left-outer join at static capacity; (table, required).
+) -> Tuple[Table, jax.Array, Optional[jax.Array]]:
+    """Traced exact left-outer join at static capacity; returns
+    ``(table, required, prefilter)`` as :func:`join_with_capacity` does.
 
     Mirrors :func:`left_outer_join`: with one condition this is the native
     outer path at ``capacity``; with several, the exact first-key inner
@@ -248,7 +255,7 @@ def left_outer_with_capacity(
     rowid = "__rowid__"
     lt = left.with_columns(**{rowid: jnp.arange(left.capacity,
                                                 dtype=jnp.int32)})
-    inner, total = join_with_capacity(
+    inner, total, prefilter = join_with_capacity(
         lt, right, on, how="inner", capacity=capacity,
         use_kernel=use_kernel, bloom_bits=bloom_bits)
     hits = jnp.zeros((left.capacity,), dtype=jnp.int32)
@@ -277,7 +284,7 @@ def left_outer_with_capacity(
     valid = jnp.concatenate([matched_part.valid, unmatched_part.valid])
     return Table(
         columns={k: v for k, v in cols.items() if k != rowid}, valid=valid
-    ), total
+    ), total, prefilter
 
 
 @functools.partial(

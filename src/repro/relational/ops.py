@@ -2,7 +2,7 @@
 from __future__ import annotations
 
 import functools
-from typing import Optional, Sequence, Tuple
+from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -188,10 +188,15 @@ def table_digest(table: Table) -> str:
     content-address derived artifacts (e.g. the engine's CSR cache, where
     ``extgraph`` and ``ringo`` runs of one model must collide).
     """
+    return rows_digest(table.to_numpy())
+
+
+def rows_digest(data: Dict[str, np.ndarray]) -> str:
+    """:func:`table_digest` of valid rows already on the host (column name
+    to values, as :meth:`Table.to_numpy` gives them)."""
     import hashlib
 
     h = hashlib.sha1()
-    data = table.to_numpy()
     names = sorted(data)
     n = len(data[names[0]]) if names else 0
     if n:

@@ -9,7 +9,7 @@ The acceptance bars this file enforces:
   capacity utilization for every plan unit of the tpcds/dblp/imdb
   models with **zero added device syncs**: the actuals are recycled from
   the overflow check's single host sync, so an analyzed extract performs
-  exactly as many ``pipeline.sync`` transfers as a plain one.
+  exactly as many ``pipeline.wait`` host syncs as a plain one.
 * cache byte accounting is exact for numpy-backed tables and the
   byte-budget eviction never evicts the sole remaining entry.
 * the HTTP front end serves POST /v1/explain and GET /v1/traces, and the
@@ -120,7 +120,7 @@ def test_explain_analyze_reports_actuals_for_every_unit(dataset):
 
 def _sync_spans():
     spans = obs.TRACER.get(obs.TRACER.trace_ids()[-1])
-    return sum(1 for s in spans if s["name"] == "pipeline.sync")
+    return sum(1 for s in spans if s["name"] == "pipeline.wait")
 
 
 def test_explain_analyze_adds_zero_device_syncs(dataset):

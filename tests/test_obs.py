@@ -7,6 +7,8 @@ Covers the contracts the instrumentation is built on:
 * eager and compiled engines emit the same structural span tree,
 * K coalesced requests produce one leader trace and K-1 follower spans
   linked to it,
+* spans reach a profiler trace's host plane by name, and the ``bench.``
+  prefix is refused,
 * a served extract's trace attributes >= 95% of its wall time, and the
   HTTP front end round-trips /v1/trace and /v1/metrics (JSON + a
   parseable Prometheus text format).
@@ -172,6 +174,43 @@ def test_traced_call_breakdown_fields(dblp):
                 "csr_s", "queue_s", "coverage"):
         assert math.isfinite(bd[key]), (key, bd)
     assert bd["coverage"] >= 0.95
+
+
+def test_spans_land_on_the_profiler_host_plane(dblp, tmp_path):
+    """Under a profiler trace, a warm extract's spans appear on the
+    ``.xplane.pb`` host plane by name, on the profiler's clock."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from repro.api import ExtractionEngine
+    db, model = dblp
+    engine = ExtractionEngine(db.snapshot())
+    engine.extract(model)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        engine.extract(model)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {e.name for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events}
+    units = {n for n in names if n.startswith("unit:")}
+    assert {"engine.extract", "plan", "pipeline.dispatch",
+            "pipeline.wait"} <= names
+    assert units and all(u.split(":", 1)[1] for u in units)
+
+
+def test_bench_prefix_is_reserved():
+    t = Tracer()
+    with pytest.raises(ValueError):
+        t.span("bench.window")
+    with pytest.raises(ValueError):
+        t.record("bench.op", 0.0, 1.0)
+    with t.span("benchmark.ok"):
+        pass
 
 
 # -- serving: coalescing + trace links ---------------------------------------

@@ -173,3 +173,52 @@ def test_failed_background_recompile_is_counted():
     pipeline._submit_reopt(lambda: 1 // 0)
     pipeline.drain_reoptimizations()
     assert obs.REGISTRY.value(pipeline.REOPT_FAILURES) == before + 1
+
+
+def test_bloom_counts_reach_stats_and_registry(tpcds_db):
+    """The pipeline sums each kept attempt's prefilter pairs into its stats
+    and ``pipeline_bloom_rows_total``; with the prefilter off it counts
+    nothing."""
+    from repro import obs
+
+    model = fraud_model("store")
+    plan = plan_queries(tpcds_db.snapshot(), model.queries(), "extgraph")
+
+    def registry():
+        return [obs.REGISTRY.value("pipeline_bloom_rows_total",
+                                   outcome=o) for o in ("probed", "passed")]
+
+    off = PipelineCompiler(use_kernel=False, use_bloom=False)
+    before = registry()
+    run_plan(tpcds_db.snapshot(), plan, compiler=off)
+    assert (off.stats["bloom_probed"], off.stats["bloom_passed"]) == (0, 0)
+    assert registry() == before
+
+    on = PipelineCompiler(use_kernel=False, use_bloom=True)
+    run_plan(tpcds_db.snapshot(), plan, compiler=on)
+    probed, passed = on.stats["bloom_probed"], on.stats["bloom_passed"]
+    assert 0 < passed <= probed
+    assert registry() == [before[0] + probed, before[1] + passed]
+
+
+def test_merged_unit_hlo_names_its_unit_and_steps(tpcds_db):
+    """A merged unit's executable is named after its members, and its ops
+    carry the ``join:`` / ``outer:`` / ``dedup:`` scopes (a scan without a
+    filter adds no op)."""
+    import re
+
+    from repro.core import pipeline
+
+    model = recommendation_model("store")
+    plan = plan_queries(tpcds_db.snapshot(), model.queries(), "extgraph-oj")
+    merged = [u.group for u in plan.units if not u.is_single]
+    assert merged, "expected a JS-OJ group"
+    run_plan(tpcds_db.snapshot(), plan, compiler=PipelineCompiler())
+    texts = {exe.as_text().split(",", 1)[0]: exe.as_text()
+             for exe in pipeline._EXECUTABLE_CACHE.values()}
+    label = re.sub(r"[^0-9A-Za-z_]", "_", "_".join(merged[0].member_names()))
+    module = "HloModule jit_unit_" + label
+    assert module in texts, sorted(texts)
+    scopes = set(re.findall(r'op_name="jit\(unit_\w+\)/(\w+):',
+                            texts[module]))
+    assert {"join", "outer", "dedup"} <= scopes, scopes

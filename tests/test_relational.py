@@ -101,3 +101,45 @@ def test_filter_dedup_compact_concat():
     assert v[: int(f.num_rows())].all() and not v[int(f.num_rows()):].any()
     cc = concat([t, t])
     assert int(cc.num_rows()) == 10
+
+
+def _bloom_hits_np(keys, bits, num_hashes=2):
+    """Numpy twin of the Bloom probe: every hashed bit of a key is set."""
+    ks = keys.astype(np.uint32)
+    hit = np.ones(keys.shape, bool)
+    for i in range(num_hashes):
+        h = ks * np.uint32(2654435761 + 40503 * i) + np.uint32(i * 97)
+        h ^= h >> np.uint32(15)
+        hit &= bits[(h % np.uint32(bits.size)).astype(np.int64)] > 0
+    return hit
+
+
+def test_bloom_prefilter_counts_match_numpy():
+    """The join's (probed, passed) pair equals a numpy count over the same
+    probe keys and bitset; passed <= probed, and no key that matches is
+    filtered out.  Without a prefilter the join returns no counts."""
+    from repro.kernels import ops as kops
+    from repro.relational.join import NULL_KEY64, join_with_capacity
+
+    rng = np.random.default_rng(3)
+    lk = rng.integers(0, 5000, size=900).astype(np.int32)
+    lk[::17] = NULL_KEY64                       # null keys are not probed
+    rk = rng.integers(0, 5000, size=300).astype(np.int32)
+    left = Table.from_arrays(k=lk).mask(np.arange(900) % 11 != 0).prefix("L")
+    right = Table.from_arrays(k=rk).prefix("R")
+    bits_n = 256                                # small: some keys collide
+    _, required, counts = join_with_capacity(
+        left, right, [("L.k", "R.k")], capacity=4096, bloom_bits=bits_n)
+    probed, passed = (int(x) for x in np.asarray(counts))
+
+    bits = np.asarray(kops.bloom_build(
+        np.asarray(right["R.k"]), np.asarray(right.valid), bits_n))
+    keyed = np.asarray(left.valid) & (lk != NULL_KEY64)
+    assert probed == int(keyed.sum())
+    assert passed == int((keyed & _bloom_hits_np(lk, bits)).sum())
+    assert int((keyed & np.isin(lk, rk)).sum()) <= passed < probed
+
+    _, required_off, none = join_with_capacity(
+        left, right, [("L.k", "R.k")], capacity=4096)
+    assert none is None
+    assert int(required_off) == int(required)

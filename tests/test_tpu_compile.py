@@ -7,6 +7,7 @@ over the fast-memory budget.  These tests compile every kernel with
 the widths of the fraud model on TPC-DS at published row counts
 (``make_tpcds(sf=1000)``: 2.88M store_sales rows, so 2.88M edges per label
 over ~600k vertices; a 500k-row customer build side for the join probe).
+Each kernel's HLO instruction carries its pinned ``pallas_call`` name.
 Nothing runs: a compile that passes is not a chip run.
 
 The topology is described inside a module-scoped fixture, never while a
@@ -14,6 +15,7 @@ module is imported: only one process may load the TPU library, and under
 several test workers the others would fail or collect different tests.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -91,4 +93,8 @@ def test_kernel_compiles_for_v5e(one_chip, kernel):
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
             for shape, dtype in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the kernel's pinned name is its HLO instruction's, which a profile
+    # shows and the benchmark's roofline readers match
+    assert re.search(rf"%{kernel}\.\d+ = ", text), kernel
