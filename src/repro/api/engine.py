@@ -62,7 +62,7 @@ from repro.core.model import (
     model_signature,
     model_tables,
 )
-from repro.core.pipeline import PipelineCompiler
+from repro.core.pipeline import PipelineCompiler, probe_capacities
 from repro.core.planner import ExtractionPlan
 from repro.core.shared import SharedPattern
 from repro.incremental.changelog import MergedDelta, merge_deltas
@@ -789,9 +789,9 @@ class ExtractionEngine:
         is a plan-cache hit.  Per plan unit the report carries the chosen
         join order, the MV-reuse vs. outer-join decision with the
         cost-model numbers behind it (chosen plan vs. the no-sharing
-        baseline), the pow-2 capacity buckets with their provenance
-        (proven by a prior run vs. freshly estimated), and the
-        executable-cache state.
+        baseline), each step's capacity, how it was sized (estimate
+        bucket or probe-side bound) and its provenance (proven by a prior
+        run vs. freshly estimated), and the executable-cache state.
 
         ``analyze=True`` (or :meth:`explain_analyze`) first runs the full
         extract through the normal hot path, then reads back the per-step
@@ -924,6 +924,7 @@ class ExtractionEngine:
             source, state, record = "estimated", "eager", None
         actual = record["actual"] if record else None
         labels = _step_labels(kind, unit, prog.orders)
+        probes = probe_capacities(rdb, prog)
         steps = tuple(
             obs.StepReport(
                 label=labels[i] if i < len(labels) else f"step {i + 1}",
@@ -932,7 +933,9 @@ class ExtractionEngine:
                           if i < len(prog.est_rows) else 0.0),
                 actual_rows=(int(actual[i])
                              if actual is not None and i < len(actual)
-                             else None))
+                             else None),
+                sizing=prog.sizing[i],
+                probe_capacity=probes[i])
             for i, cap in enumerate(prog.capacities))
         return obs.UnitReport(
             name=name, kind=report_kind, inputs=tuple(prog.inputs),

@@ -172,7 +172,8 @@ def step_expansions(
     so the capacity an intermediate buffer needs is the first-condition-only
     expansion — potentially much larger than the all-conditions estimate
     that drives :func:`estimate_query`.  Returns one estimate per join step
-    along ``order`` (the pipeline compiler pow-2-buckets these); the running
+    along ``order`` (the pipeline compiler pow-2-buckets these where no
+    unique key bounds the step); the running
     estimate fed into later steps does use every condition, matching what
     the post-filters leave behind.
     """
@@ -197,7 +198,7 @@ def view_stats_from_estimate(est: QueryEstimate) -> TableStats:
     """Estimated stats attached to a view when it is materialized."""
     distinct = {f"{a}.{c}": int(max(1, v)) for (a, c), v in est.ndv.items()}
     return TableStats(rows=int(max(1, est.rows)), distinct=distinct,
-                      width=est.width)
+                      width=est.width, estimated=True)
 
 
 def view_cost(est: QueryEstimate) -> float:

@@ -29,7 +29,10 @@ class TableStats:
 
     ``distinct`` and ``minmax`` cover int key columns only.  After a
     mutation both are *approximations* (see the ``_stats_after_*``
-    helpers); ``analyze()`` restores exact values.
+    helpers); ``analyze()`` restores exact values.  ``estimated`` marks
+    stats that no pass over the rows counted (a view's, from the cost
+    model's estimate): their ``distinct`` is capped at the row estimate, so
+    it proves no key unique.
     """
 
     rows: int
@@ -37,6 +40,12 @@ class TableStats:
     width: int  # columns (4 bytes each, all int32/float32)
     minmax: Dict[str, Tuple[int, int]] = dataclasses.field(
         default_factory=dict)
+    estimated: bool = False
+
+    def unique(self, col: str) -> bool:
+        """Does every row hold its own value of ``col``, by counted stats?"""
+        return (not self.estimated and col in self.distinct
+                and self.distinct[col] >= self.rows)
 
     def bytes(self) -> int:
         return self.rows * self.width * 4
@@ -85,7 +94,7 @@ def _stats_after_insert(st: TableStats, plus: TableStats) -> TableStats:
         else:
             minmax[c] = (lo, hi)
     return TableStats(rows=rows, distinct=distinct, width=st.width,
-                      minmax=minmax)
+                      minmax=minmax, estimated=st.estimated)
 
 
 def _stats_after_delete(st: TableStats, minus_rows: int) -> TableStats:
@@ -100,12 +109,13 @@ def _stats_after_delete(st: TableStats, minus_rows: int) -> TableStats:
     rows = max(0, st.rows - minus_rows)
     if rows == 0:
         return TableStats(rows=0, distinct={c: 0 for c in st.distinct},
-                          width=st.width, minmax={})
+                          width=st.width, minmax={},
+                          estimated=st.estimated)
     frac = rows / st.rows
     distinct = {c: max(1, min(rows, int(round(n * frac))))
                 for c, n in st.distinct.items()}
     return TableStats(rows=rows, distinct=distinct, width=st.width,
-                      minmax=dict(st.minmax))
+                      minmax=dict(st.minmax), estimated=st.estimated)
 
 
 RowsLike = Union[Table, Mapping[str, np.ndarray]]

@@ -7,9 +7,12 @@ barrier per operator is exactly what GraphGen and the Vertica graph work
 identify as the cost of operator-at-a-time extraction.  This module removes
 it:
 
-* **Capacity planning** — the cost model's cardinality estimates
-  (:func:`repro.core.cost.step_expansions`) pre-size every intermediate to a
-  pow-2-bucketed static capacity *before* execution.
+* **Capacity planning** — every intermediate gets a static capacity
+  *before* execution.  A step whose build side is keyed by a unique column
+  (by counted stats) matches each probe row at most once, so it takes its
+  probe side's static capacity; every other step takes the cost model's
+  cardinality estimate (:func:`repro.core.cost.step_expansions`) times a
+  margin, rounded up to a power of two.
 * **Whole-unit tracing** — each :class:`~repro.core.planner.PlanUnit`'s full
   dataflow (scans → join chain → post-filters → outer-join branches → edge
   projection) is traced into **one** jitted executable with no host syncs in
@@ -273,6 +276,9 @@ class UnitProgram:
     ``capacities`` holds one static capacity per join step, in the exact
     order the traced function consumes them: main/S chain first, then per
     branch its inner chain followed by its outer-join attachment.
+    ``sizing`` says how each was chosen: ``"probe_bound"`` steps hold their
+    probe side's static capacity (the build key is unique), ``"estimate"``
+    steps a power of two from the cost model or an overflow retry.
     """
 
     kind: str
@@ -282,17 +288,129 @@ class UnitProgram:
     inputs: Tuple[str, ...]               # base-table / view names read
     signature: object                     # hashable cache identity
     est_rows: Tuple[float, ...] = ()      # cost-model rows per join step
+    sizing: Tuple[str, ...] = ()          # ESTIMATE | PROBE_BOUND per step
 
 
 # ---------------------------------------------------------------------------
 # Capacity planning
 # ---------------------------------------------------------------------------
 
+ESTIMATE = "estimate"
+PROBE_BOUND = "probe_bound"
+
+
 def _bucket(rows: float, margin: float, clamp: Optional[int]) -> int:
     cap = _round_capacity(int(rows * margin))
     if clamp is not None:
         cap = min(cap, max(8, clamp))
     return cap
+
+
+def _step_sides(kind: str, unit, orders) -> Tuple[Tuple, ...]:
+    """``(build, probe)`` of each join step, in ``capacities`` order.
+
+    ``build`` is the ``(table, column)`` the step sorts and probes: the
+    first condition's column on the relation joined in (further conditions
+    are post-filters).  It is ``None`` where the build side is a branch's
+    join result rather than a scanned table.  ``probe`` lists what the
+    probe side's static capacity sums: table names (``scan_table`` masks
+    and never compacts) and indices of earlier steps (a join's output has
+    its step's capacity; an outer attachment with several link conditions
+    appends one row per left row, see ``left_outer_with_capacity``).
+    """
+    steps: List[Tuple] = []
+
+    def chain(query: JoinQuery, order) -> Tuple:
+        probe: Tuple = (query.relation(order[0]).table,)
+        for alias, conds, _ in join_schedule(query, order):
+            build = (query.relation(alias).table,
+                     conds[0].oriented_from(alias).lcol)
+            steps.append((build, probe))
+            probe = (len(steps) - 1,)
+        return probe
+
+    if kind != "merged":
+        chain(unit, orders[0])
+        return tuple(steps)
+    left = chain(shared_query(unit), orders[0])
+    for b, order in zip(unit.branches, orders[1:]):
+        if not b.relations:
+            continue                     # indicator-only: no join step
+        build = None
+        if len(b.relations) > 1:
+            chain(b.as_query(), order)
+        else:
+            rel = b.relations[0]
+            build = (rel.table, b.link_conds[0].oriented_from(rel.alias).lcol)
+        steps.append((build, left))
+        step = len(steps) - 1
+        left = (step,) if len(b.link_conds) == 1 else (step,) + left
+    return tuple(steps)
+
+
+def _probe_capacity(db: Database, probe: Tuple,
+                    caps: Sequence[int]) -> Optional[int]:
+    """Static capacity of a probe side (``None`` if a table is missing)."""
+    total = 0
+    for part in probe:
+        if isinstance(part, int):
+            total += caps[part]
+        elif part in db.tables:
+            total += db.tables[part].capacity
+        else:
+            return None                  # an unmaterialized view
+    return total
+
+
+def probe_capacities(db: Database,
+                     program: "UnitProgram") -> Tuple[Optional[int], ...]:
+    """Each step's probe-side static capacity under the program's own
+    capacities and ``db``'s tables; ``None`` where an input is missing."""
+    caps = program.capacities
+    return tuple(_probe_capacity(db, probe, caps) for _, probe in
+                 _step_sides(program.kind, program.unit, program.orders))
+
+
+def _size_steps(db: Database, kind: str, unit, orders, rows: Sequence[float],
+                margin: float, clamp: Optional[int]):
+    """``(capacities, sizing)`` of a freshly planned unit.
+
+    A step whose build key is unique by counted stats matches each probe
+    row at most once, so its probe side's capacity bounds it: it takes
+    ``min(estimate bucket, probe capacity)`` and is ``PROBE_BOUND`` where
+    the probe side is the smaller.  Every other step keeps the estimate
+    bucket.  No capacity grows under the rule.
+    """
+    caps: List[int] = []
+    sizing: List[str] = []
+    for r, (build, probe) in zip(rows, _step_sides(kind, unit, orders)):
+        cap = _bucket(r, margin, clamp)
+        st = db.stats.get(build[0]) if build else None
+        bound = (_probe_capacity(db, probe, caps)
+                 if st is not None and st.unique(build[1]) else None)
+        if bound and bound <= cap:
+            caps.append(bound)
+            sizing.append(PROBE_BOUND)
+        else:
+            caps.append(cap)
+            sizing.append(ESTIMATE)
+    return tuple(caps), tuple(sizing)
+
+
+def _bind(db: Database, prog: "UnitProgram") -> "UnitProgram":
+    """``prog`` with each ``PROBE_BOUND`` step re-set to its probe side's
+    current capacity: a fact table that grew since the program was planned
+    (or a retry that grew an upstream step) moves the bound too."""
+    if PROBE_BOUND not in prog.sizing:
+        return prog
+    caps = list(prog.capacities)
+    sides = _step_sides(prog.kind, prog.unit, prog.orders)
+    for i, (_, probe) in enumerate(sides):
+        if prog.sizing[i] == PROBE_BOUND:
+            caps[i] = _probe_capacity(db, probe, caps) or caps[i]
+    if tuple(caps) == prog.capacities:
+        return prog
+    return dataclasses.replace(prog, capacities=tuple(caps))
 
 
 def _query_inputs(query: JoinQuery) -> Tuple[str, ...]:
@@ -310,17 +428,21 @@ def build_query_program(
     db: Database, query: JoinQuery, edges: bool,
     margin: float = CAPACITY_MARGIN, clamp: Optional[int] = None,
 ) -> UnitProgram:
-    """Pre-size a single query's join chain from the cost model."""
+    """Pre-size a single query's join chain (see :func:`_size_steps`)."""
     est = estimate_query(db, query)
+    orders = (est.order,)
     rows = tuple(step_expansions(db, query, est.order))
+    kind = "edges" if edges else "query"
+    caps, sizing = _size_steps(db, kind, query, orders, rows, margin, clamp)
     return UnitProgram(
-        kind="edges" if edges else "query",
+        kind=kind,
         unit=query,
-        orders=(est.order,),
-        capacities=tuple(_bucket(r, margin, clamp) for r in rows),
+        orders=orders,
+        capacities=caps,
         inputs=_query_inputs(query),
         signature=("q", query_signature(query), edges),
         est_rows=rows,
+        sizing=sizing,
     )
 
 
@@ -330,10 +452,13 @@ def build_merged_program(
 ) -> UnitProgram:
     """Pre-size a JS-OJ group: S chain, branch chains, outer attachments.
 
-    Outer-join capacities follow Eq 3/4's expansion estimate but on the
-    *first* link condition only (further conditions are post-filters of the
-    static expansion, mirroring the executor's contract); the running row
-    estimate between branches uses every condition.
+    Outer-join estimates follow Eq 3/4's expansion but on the *first* link
+    condition only (further conditions are post-filters of the static
+    expansion, mirroring the executor's contract); the running row
+    estimate between branches uses every condition.  An attachment to a
+    one-relation branch keyed by a unique column needs one slot per valid
+    left row, and so takes the running S table's capacity
+    (:func:`_size_steps`).
     """
     sq = shared_query(merged)
     s_est = estimate_query(db, sq)
@@ -364,14 +489,17 @@ def build_merged_program(
         # unmatched left rows also occupy slots (counts = max(match, 1))
         cap_rows.append(rows * max(1.0, b_rel.rows * sel_first) + rows)
         rows *= max(1.0, b_rel.rows * sel_all)
+    caps, sizing = _size_steps(db, "merged", merged, tuple(orders), cap_rows,
+                               margin, clamp)
     return UnitProgram(
         kind="merged",
         unit=merged,
         orders=tuple(orders),
-        capacities=tuple(_bucket(r, margin, clamp) for r in cap_rows),
+        capacities=caps,
         inputs=_merged_inputs(merged),
         signature=("m", merged),
         est_rows=tuple(cap_rows),
+        sizing=sizing,
     )
 
 
@@ -652,6 +780,22 @@ def _bloom_counter(outcome: str):
         outcome=outcome)
 
 
+def _sizing_counter(sizing: str):
+    return obs.REGISTRY.counter(
+        "pipeline_capacity_steps_total",
+        help="Join steps of the programs built, by how their capacity was "
+             "sized (estimate or probe_bound).",
+        sizing=sizing)
+
+
+def _slots_counter(rows: str):
+    return obs.REGISTRY.counter(
+        "pipeline_capacity_rows_total",
+        help="Join-step slots of the kept attempts: rows used, and the "
+             "capacity allotted.",
+        rows=rows)
+
+
 class PipelineCompiler:
     """Compiles plan units into cached, overflow-safe jitted executables.
 
@@ -767,7 +911,18 @@ class PipelineCompiler:
     def _stats_fp(self, db: Database, inputs: Sequence[str]) -> Tuple:
         return tuple((n, db.stats[n].fingerprint()) for n in inputs)
 
+    def _build(self, db: Database, kind: str, unit) -> UnitProgram:
+        if kind == "merged":
+            return build_merged_program(db, unit, self.margin,
+                                        self.initial_capacity_clamp)
+        return build_query_program(db, unit, edges=(kind == "edges"),
+                                   margin=self.margin,
+                                   clamp=self.initial_capacity_clamp)
+
     def _program(self, db: Database, kind: str, unit):
+        """``(pkey, program)``: the stats-keyed program, else the memo's,
+        else a fresh build.  Either way its ``PROBE_BOUND`` steps are
+        re-bound to the probe sides' current capacities (:func:`_bind`)."""
         inputs = (_merged_inputs(unit) if kind == "merged"
                   else _query_inputs(unit))
         pkey = (kind, unit, self._stats_fp(db, inputs))
@@ -775,23 +930,19 @@ class PipelineCompiler:
             prog = self._programs.get(pkey)
             if prog is not None:
                 self._programs.move_to_end(pkey)
-                return pkey, prog
+                return pkey, _bind(db, prog)
         with self._lock:
             prog = self._unit_memo.get((kind, unit))
         if prog is None:
-            if kind == "merged":
-                prog = build_merged_program(db, unit, self.margin,
-                                            self.initial_capacity_clamp)
-            else:
-                prog = build_query_program(db, unit, edges=(kind == "edges"),
-                                           margin=self.margin,
-                                           clamp=self.initial_capacity_clamp)
+            prog = self._build(db, kind, unit)
+            for sizing in prog.sizing:
+                _sizing_counter(sizing).inc()
             self._remember_unit(kind, unit, prog)
         with self._lock:
             self._programs[pkey] = prog
             while len(self._programs) > self.max_programs:
                 self._programs.popitem(last=False)
-        return pkey, prog
+        return pkey, _bind(db, prog)
 
     def _executable(self, prog: UnitProgram, inputs: Dict[str, Table]):
         key = (prog.signature, prog.orders, prog.capacities,
@@ -838,7 +989,8 @@ class PipelineCompiler:
         device round-trips.  The estimate ratio is (actual+1)/(predicted+1)
         — log₂ buckets make under- and over-estimates symmetric around 1 —
         and utilization is actual/capacity (1.0 = a bucket about to
-        overflow).  The per-step values are also retained by program
+        overflow); ``pipeline_capacity_rows_total`` sums both over the
+        steps.  The per-step values are also retained by program
         signature for :meth:`last_rows` (EXPLAIN ANALYZE).
         """
         if need.size == 0:
@@ -848,6 +1000,8 @@ class PipelineCompiler:
             help="Actual/predicted rows per join step (1 = perfect "
                  "cost-model estimate).", kind=prog.kind)
         actual = [int(n) for n in need.tolist()]
+        _slots_counter("used").inc(sum(actual))
+        _slots_counter("allotted").inc(sum(int(c) for c in caps))
         for i, n in enumerate(actual):
             if i < len(prog.est_rows):
                 ratio_h.observe((n + 1.0) / (prog.est_rows[i] + 1.0))
@@ -899,20 +1053,12 @@ class PipelineCompiler:
                   else _query_inputs(unit))
         pkey = (kind, unit, self._stats_fp(db, inputs))
         with self._lock:
-            prog = self._programs.get(pkey)
-            if prog is not None:
-                return prog, "programs"
-            prog = self._unit_memo.get((kind, unit))
-            if prog is not None:
-                return prog, "memo"
-        if kind == "merged":
-            prog = build_merged_program(db, unit, self.margin,
-                                        self.initial_capacity_clamp)
-        else:
-            prog = build_query_program(db, unit, edges=(kind == "edges"),
-                                       margin=self.margin,
-                                       clamp=self.initial_capacity_clamp)
-        return prog, "estimated"
+            prog, source = self._programs.get(pkey), "programs"
+            if prog is None:
+                prog, source = self._unit_memo.get((kind, unit)), "memo"
+        if prog is None:
+            return self._build(db, kind, unit), "estimated"
+        return _bind(db, prog), source
 
     def executable_state(self, prog: UnitProgram,
                          tables: Dict[str, Table]) -> str:
@@ -940,17 +1086,20 @@ class PipelineCompiler:
         least doubles it; steps downstream of a truncation may only reveal
         their true requirement on the retry, so the loop runs to a fixpoint
         (bounded by the step count — each round fixes at least the first
-        overflowing step for good).  The synced vector also carries each
+        overflowing step for good).  A ``PROBE_BOUND`` step that overflows
+        has a build key that is not unique after all: it becomes an
+        ``ESTIMATE`` step at its grown capacity, and the other bound steps
+        follow their probe sides.  The synced vector also carries each
         Bloom prefilter's (probed, passed) rows, counted on the attempt
         that is kept (:meth:`_count_prefilter`).
         """
         inputs = {n: db.tables[n] for n in prog.inputs}
-        caps = prog.capacities
+        cur = prog
         attempts = self.max_retries
         if attempts is None:
-            attempts = max(8, len(caps) + 1)
+            attempts = max(8, len(prog.capacities) + 1)
         for _ in range(attempts + 1):
-            cur = dataclasses.replace(prog, capacities=caps)
+            caps = cur.capacities
             exe = self._executable(cur, inputs)
             with obs.span("pipeline.dispatch", category="execute",
                           detail=True, kind=prog.kind):
@@ -963,7 +1112,7 @@ class PipelineCompiler:
                     (need <= np.asarray(caps, dtype=np.int64)).all()):
                 self._observe_rows(prog, caps, need)
                 self._count_prefilter(prefilter)
-                if caps != prog.capacities:
+                if cur is not prog:
                     with self._lock:                  # skip retries next time
                         self._programs[pkey] = cur
                     # stats-independent memo too: future rebuilds of this
@@ -972,9 +1121,14 @@ class PipelineCompiler:
                     self._remember_unit(prog.kind, prog.unit, cur)
                 return out
             self._bump("retries")
-            caps = tuple(
-                _round_capacity(int(n)) if int(n) > c else c
-                for n, c in zip(need.tolist(), caps))
+            over = [int(n) > c for n, c in zip(need.tolist(), caps)]
+            cur = _bind(db, dataclasses.replace(
+                cur,
+                capacities=tuple(_round_capacity(int(n)) if o else c
+                                 for n, c, o in zip(need.tolist(), caps,
+                                                    over)),
+                sizing=tuple(ESTIMATE if o else s
+                             for s, o in zip(cur.sizing, over))))
         raise RuntimeError(
             f"pipeline overflow retry did not converge for "
-            f"{prog.signature!r} (capacities {caps})")
+            f"{prog.signature!r} (capacities {cur.capacities})")

@@ -13,8 +13,9 @@ The semi-join runs as a **compiled pipeline**: each check is phrased as
 one canonical two-relation :class:`JoinQuery` over tables named
 ``probe``/``build``, so the :class:`PipelineCompiler`'s ``(kind, unit)``
 memo pins one program for *every* check and the process-wide executable
-store keys only on the pow-2 capacity buckets — checks against same-sized
-key spaces reuse one jitted executable (and get the ``bloom`` /
+store keys only on the capacities — the ``build`` side is unique, so the
+step takes the ``probe`` side's fixed capacity, and checks against
+same-sized key spaces reuse one jitted executable (and get the ``bloom`` /
 ``sorted_probe`` kernels wherever extraction does).  ``compiler=None``
 falls back to the eager :func:`semi_join_mask` reference path.
 

@@ -90,14 +90,16 @@ def _stats_to_dict(st: TableStats) -> Dict[str, object]:
     return {"rows": st.rows, "width": st.width,
             "distinct": dict(st.distinct),
             "minmax": {c: [int(lo), int(hi)]
-                       for c, (lo, hi) in st.minmax.items()}}
+                       for c, (lo, hi) in st.minmax.items()},
+            "estimated": st.estimated}
 
 
 def _stats_from_dict(d: Dict[str, object]) -> TableStats:
     return TableStats(rows=int(d["rows"]), width=int(d["width"]),
                       distinct={k: int(v) for k, v in d["distinct"].items()},
                       minmax={c: (int(lo), int(hi))
-                              for c, (lo, hi) in d["minmax"].items()})
+                              for c, (lo, hi) in d["minmax"].items()},
+                      estimated=bool(d.get("estimated", False)))
 
 
 def write_manifest(dirpath: str, db: Database,

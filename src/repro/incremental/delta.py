@@ -134,7 +134,7 @@ class DeltaExecutor:
     ``old_tables`` / ``old_stats`` describe the base tables as of the
     consumer's changelog cursor (the immutable Table objects it captured);
     ``db`` provides the new state.  With a ``compiler`` each term runs as
-    one fused executable (pow-2 capacities, overflow retry); without, the
+    one fused executable (planned capacities, overflow retry); without, the
     eager two-phase path.
     """
 

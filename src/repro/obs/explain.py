@@ -13,8 +13,9 @@ returned graph hides:
 * whether sharable subqueries became a materialized view (JS-MV) or an
   outer-join merge (JS-OJ), with the Eq. 1-5 cost numbers behind the
   decision (chosen plan vs. the no-sharing baseline),
-* the pow-2 capacity bucket of every join step and whether the bucket
-  came from a proven prior run or a fresh cost-model estimate,
+* the capacity of every join step, how it was sized (a pow-2 bucket of
+  the estimate, or its probe side's capacity where the build key is
+  unique), and whether it came from a proven prior run or a fresh plan,
 * the executable-cache state (will this plan compile or just launch?),
 * and — after ANALYZE — estimated vs. *actual* rows per step plus
   capacity utilization, read back from the host-side overflow-check
@@ -30,12 +31,20 @@ __all__ = ["StepReport", "UnitReport", "PlanReport"]
 
 @dataclasses.dataclass(frozen=True)
 class StepReport:
-    """One join step of a unit's chain — one pow-2 capacity bucket."""
+    """One join step of a unit's chain and the capacity it was allotted.
+
+    ``sizing`` is ``"estimate"`` (a pow-2 bucket of the cost-model
+    estimate, or of an overflow retry's exact need) or ``"probe_bound"``
+    (the build key is unique, so the step holds ``probe_capacity``, its
+    probe side's static capacity).
+    """
 
     label: str                        # e.g. "join item", "outer-join b0"
-    capacity: int                     # pow-2 buffer rows allotted
+    capacity: int                     # buffer rows allotted
     est_rows: float                   # cost-model estimate (Eq. 1-3)
     actual_rows: Optional[int] = None  # ANALYZE only; host-side, no sync
+    sizing: str = "estimate"          # "estimate" | "probe_bound"
+    probe_capacity: Optional[int] = None  # None: an input not materialized
 
     @property
     def utilization(self) -> Optional[float]:
@@ -54,6 +63,8 @@ class StepReport:
     def to_json(self) -> Dict[str, object]:
         return {"label": self.label,
                 "capacity": int(self.capacity),
+                "sizing": self.sizing,
+                "probe_capacity": self.probe_capacity,
                 "est_rows": float(self.est_rows),
                 "actual_rows": self.actual_rows,
                 "utilization": self.utilization,
@@ -168,7 +179,7 @@ def _entry_lines(u: UnitReport, tag: str) -> list:
         lines.append("  members: " + ", ".join(u.members))
     for i, s in enumerate(u.steps):
         row = (f"  #{i + 1} {s.label:<26} cap={s.capacity:<8d} "
-               f"est={s.est_rows:<12.1f}")
+               f"{s.sizing:<11} est={s.est_rows:<12.1f}")
         if s.actual_rows is not None:
             row += (f" actual={s.actual_rows:<8d} "
                     f"util={s.utilization:.2f} "

@@ -306,9 +306,11 @@ def round_capacity(n: int) -> int:
     """Smallest pow-2 capacity strictly above ``n`` (min 8).
 
     The one capacity-bucketing rule shared by the eager two-phase path,
-    the compiled pipeline, and incremental delta tables — bucketing keeps
-    jitted shapes stable across requests (and across refreshes at similar
-    churn), which is what makes executable caches hit.
+    the compiled pipeline's estimate-sized steps and overflow retries, and
+    incremental delta tables — bucketing keeps jitted shapes stable across
+    requests (and across refreshes at similar churn), which is what makes
+    executable caches hit.  A pipeline step into a unique key needs no
+    bucket: it takes its probe side's capacity, already a static shape.
     """
     return max(8, int(1 << int(np.ceil(np.log2(max(n, 1) + 1)))))
 

@@ -3,8 +3,9 @@
 The acceptance bars this file enforces:
 
 * ``engine.explain`` is *free*: it reports the chosen plan — join orders,
-  MV-vs-outer-join decision with cost-model numbers, pow-2 capacities,
-  executable-cache state — without running a single extract.
+  MV-vs-outer-join decision with cost-model numbers, capacities and how
+  each was sized, executable-cache state — without running a single
+  extract.
 * ``engine.explain_analyze`` reports estimated-vs-actual rows and
   capacity utilization for every plan unit of the tpcds/dblp/imdb
   models with **zero added device syncs**: the actuals are recycled from
@@ -66,8 +67,14 @@ def test_explain_runs_nothing_and_reports_the_plan(dataset):
         assert u.capacity_source in ("programs", "memo", "estimated")
         assert len(u.steps) == len(u.capacities)
         for s in u.steps:
-            # the paper's static-shape contract: every capacity pow-2
-            assert s.capacity > 0 and s.capacity & (s.capacity - 1) == 0
+            # the static-shape contract: an estimate-sized step is a pow-2
+            # bucket; a step with a unique build key holds exactly its
+            # probe side's static capacity
+            assert s.sizing in ("estimate", "probe_bound")
+            if s.sizing == "estimate":
+                assert s.capacity > 0 and s.capacity & (s.capacity - 1) == 0
+            else:
+                assert s.capacity == s.probe_capacity > 0
             assert math.isfinite(s.est_rows) and s.est_rows >= 0
             assert s.actual_rows is None and s.utilization is None
         if u.kind == "merged":
