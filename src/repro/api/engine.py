@@ -736,8 +736,11 @@ class ExtractionEngine:
                     self._plans._event("misses")
                     cached = [ViewDef(cv.name, cv.pattern)
                               for cv in self._views.values()]
-                    plan = plan_queries(rdb, queries, method,
-                                        verbose=verbose, cached_views=cached)
+                    # Algorithm 2's search, on a plan-cache miss only
+                    with obs.span("plan.search", category="plan"):
+                        plan = plan_queries(rdb, queries, method,
+                                            verbose=verbose,
+                                            cached_views=cached)
                     # fault site before the fill: an injected failure loses
                     # only the cache entry, and a retry rebuilds it
                     faults.fire("engine.cache_fill")

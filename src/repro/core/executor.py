@@ -11,6 +11,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.cost import estimate_query, view_stats_from_estimate
 from repro.core.database import Database
 from repro.core.jsoj import MergedQuery, shared_query
@@ -162,13 +163,15 @@ def ensure_view(db: Database, name: str, query: JoinQuery,
     pattern — an engine cache hit.  Returns True iff the view was built.
     With a :class:`repro.core.pipeline.PipelineCompiler` the view query runs
     as one fused jitted executable instead of the eager two-phase path.
+    Span ``view.build``: the materialization, opened only when it runs.
     """
     if name in db.tables:
         return False
-    est = estimate_query(db, query)
-    if compiler is None:
-        result = execute_query(db, query)
-    else:
-        result = compiler.run_query(db, query)
-    db.add_view(name, result, view_stats_from_estimate(est))
+    with obs.span("view.build", category="execute"):
+        est = estimate_query(db, query)
+        if compiler is None:
+            result = execute_query(db, query)
+        else:
+            result = compiler.run_query(db, query)
+        db.add_view(name, result, view_stats_from_estimate(est))
     return True

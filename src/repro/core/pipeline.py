@@ -796,6 +796,14 @@ def _slots_counter(rows: str):
         rows=rows)
 
 
+def _estimate_slots_counter(rows: str):
+    return obs.REGISTRY.counter(
+        "pipeline_estimate_rows_total",
+        help="Slots of the kept attempts' estimate-sized join steps: rows "
+             "used, and the capacity allotted.",
+        rows=rows)
+
+
 class PipelineCompiler:
     """Compiles plan units into cached, overflow-safe jitted executables.
 
@@ -981,8 +989,7 @@ class PipelineCompiler:
                           capacities=list(prog.capacities), tiered=tiered)
         return exe
 
-    def _observe_rows(self, prog: UnitProgram, caps: Tuple[int, ...],
-                      need: np.ndarray) -> None:
+    def _observe_rows(self, prog: UnitProgram, need: np.ndarray) -> None:
         """Predicted-vs-actual row accounting (host-known values only).
 
         ``need`` was already synced by the overflow check, so this adds no
@@ -990,8 +997,10 @@ class PipelineCompiler:
         — log₂ buckets make under- and over-estimates symmetric around 1 —
         and utilization is actual/capacity (1.0 = a bucket about to
         overflow); ``pipeline_capacity_rows_total`` sums both over the
-        steps.  The per-step values are also retained by program
-        signature for :meth:`last_rows` (EXPLAIN ANALYZE).
+        steps, and ``pipeline_estimate_rows_total`` over the steps that
+        ``prog.sizing`` marks ``estimate``.  The per-step values are also
+        retained by program signature for :meth:`last_rows` (EXPLAIN
+        ANALYZE).  ``prog`` is the attempt kept: its capacities and sizing.
         """
         if need.size == 0:
             return
@@ -1001,14 +1010,19 @@ class PipelineCompiler:
                  "cost-model estimate).", kind=prog.kind)
         actual = [int(n) for n in need.tolist()]
         _slots_counter("used").inc(sum(actual))
-        _slots_counter("allotted").inc(sum(int(c) for c in caps))
+        caps = [int(c) for c in prog.capacities]
+        _slots_counter("allotted").inc(sum(caps))
+        estimated = [(n, c) for n, c, s in zip(actual, caps, prog.sizing)
+                     if s == ESTIMATE]
+        _estimate_slots_counter("used").inc(sum(n for n, _ in estimated))
+        _estimate_slots_counter("allotted").inc(sum(c for _, c in estimated))
         for i, n in enumerate(actual):
             if i < len(prog.est_rows):
                 ratio_h.observe((n + 1.0) / (prog.est_rows[i] + 1.0))
         with self._lock:
             self._last_rows[prog.signature] = {
                 "actual": actual,
-                "capacities": [int(c) for c in caps],
+                "capacities": caps,
                 "est_rows": [float(r) for r in prog.est_rows],
             }
             self._last_rows.move_to_end(prog.signature)
@@ -1110,7 +1124,7 @@ class PipelineCompiler:
             need, prefilter = synced[:len(caps)], synced[len(caps):]
             if need.size == 0 or bool(
                     (need <= np.asarray(caps, dtype=np.int64)).all()):
-                self._observe_rows(prog, caps, need)
+                self._observe_rows(cur, need)
                 self._count_prefilter(prefilter)
                 if cur is not prog:
                     with self._lock:                  # skip retries next time

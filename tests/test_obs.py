@@ -203,6 +203,99 @@ def test_spans_land_on_the_profiler_host_plane(dblp, tmp_path):
     assert units and all(u.split(":", 1)[1] for u in units)
 
 
+def _named(spans, name):
+    return [s for s in spans if s["name"] == name]
+
+
+def _parent(spans, span):
+    return next(s for s in spans if s["id"] == span["parent"])
+
+
+@pytest.mark.parametrize("compiled", [True, False],
+                         ids=["compiled", "eager"])
+def test_view_build_once_per_built_view_never_on_reuse(dblp, compiled):
+    from repro.api import ExtractionEngine
+    db, model = dblp
+    engine = ExtractionEngine(db.snapshot(), compiled=compiled)
+    res = engine.extract(model)
+    spans = _last_trace()
+    built = _named(spans, "view.build")
+    assert res.provenance.views_built
+    assert len(built) == len(res.provenance.views_built)
+    assert sorted(_parent(spans, s)["name"] for s in built) == sorted(
+        f"view:{name}" for name in res.provenance.views_built)
+    assert all(s["category"] == "execute" for s in built)
+
+    res = engine.extract(model)                      # the cached view
+    spans = _last_trace()
+    assert res.provenance.views_reused and not res.provenance.views_built
+    assert not _named(spans, "view.build")
+
+
+def test_plan_search_only_on_a_plan_cache_miss(dblp):
+    from repro.api import ExtractionEngine
+    db, model = dblp
+    engine = ExtractionEngine(db.snapshot())
+    assert not engine.extract(model).provenance.plan_cache_hit
+    spans = _last_trace()
+    search, = _named(spans, "plan.search")
+    assert search["category"] == "plan"
+    assert _parent(spans, search)["name"] == "plan"
+    assert engine.extract(model).provenance.plan_cache_hit
+    assert not _named(_last_trace(), "plan.search")
+    assert _named(_last_trace(), "plan")
+
+
+def test_estimate_rows_counter_counts_only_estimate_steps(dblp,
+                                                          monkeypatch):
+    from repro.api import ExtractionEngine
+    from repro.core.pipeline import ESTIMATE, PROBE_BOUND, PipelineCompiler
+    from repro.obs.metrics import MetricsRegistry
+
+    monkeypatch.setattr(obs, "REGISTRY", MetricsRegistry())
+    db, model = dblp
+    engine = ExtractionEngine(db.snapshot(), compiler=PipelineCompiler())
+    report = engine.explain_analyze(model)
+    steps = [s for u in list(report.views) + list(report.units)
+             for s in u.steps]
+    assert {s.sizing for s in steps} == {ESTIMATE, PROBE_BOUND}
+    estimate = [s for s in steps if s.sizing == ESTIMATE]
+    value = obs.REGISTRY.value
+    assert value("pipeline_estimate_rows_total", rows="used") == sum(
+        s.actual_rows for s in estimate)
+    assert value("pipeline_estimate_rows_total", rows="allotted") == sum(
+        s.capacity for s in estimate)
+    assert value("pipeline_capacity_rows_total", rows="allotted") == sum(
+        s.capacity for s in steps)
+
+
+def test_estimate_rows_counter_stays_zero_on_the_fraud_model(monkeypatch):
+    """Every fraud step is bound by its probe side: the estimate counter
+    reads 0 while the slots of all steps read full, as before it."""
+    from bench import data as bench_data
+    from bench import harness, spec
+    from repro.api import ExtractionEngine
+    from repro.core.pipeline import PipelineCompiler
+    from repro.obs.metrics import MetricsRegistry
+
+    monkeypatch.setattr(obs, "REGISTRY", MetricsRegistry())
+    config = spec.resolve(spec.load_benchmark(),
+                          "tpcds_sf1_fraud.extract")["config"]
+    db = harness._database(bench_data.make_tables(config, 7, 200))
+    engine = ExtractionEngine(db, compiler=PipelineCompiler())
+    engine.extract(harness._graph_model(config["graph"]))
+    value = obs.REGISTRY.value
+    assert value("pipeline_estimate_rows_total", rows="used") == 0
+    assert value("pipeline_estimate_rows_total", rows="allotted") == 0
+    fact = db.tables["store_sales"].capacity
+    assert value("pipeline_capacity_rows_total", rows="used") == 3 * fact
+    assert value("pipeline_capacity_rows_total", rows="allotted") == 3 * fact
+    slots_used = spec.metric_reader("slots_used.extract")
+    run = harness.Run(config={}, traffic={}, tables={}, db=None, model=None,
+                      rng=None, ops=[{}])
+    assert slots_used.read(run) == 100.0
+
+
 def test_bench_prefix_is_reserved():
     t = Tracer()
     with pytest.raises(ValueError):
