@@ -43,6 +43,7 @@ if TYPE_CHECKING:  # pragma: no cover - annotation-only
     from repro.graph import CSRGraph
 
 from repro import obs
+from repro.core.cost import carried_minmax
 from repro.core.database import Database, Fingerprint, TableStats
 from repro.durability import faults
 from repro.core.extract import (
@@ -982,9 +983,9 @@ class ExtractionEngine:
         fingerprints alone.  Views whose changelog cursor is no longer
         serviceable are evicted (the planner will rebuild them);
         everything else gets the view query's delta applied to the cached
-        materialization, its stats row count corrected, and its
-        fingerprints/cursor advanced — so a subsequent request treats it
-        as fresh instead of rebuilding.
+        materialization, its stats row count and carried column ranges
+        corrected, and its fingerprints/cursor advanced — so a subsequent
+        request treats it as fresh instead of rebuilding.
         """
         maintained: List[str] = []
         for sig, cv in list(self._views.items()):
@@ -1003,7 +1004,9 @@ class ExtractionEngine:
                                                    edges=False)
                 table = apply_table_delta(table, plus, minus)
                 rows = int(np.asarray(table.valid).sum())
-                stats = dataclasses.replace(stats, rows=rows)
+                stats = dataclasses.replace(
+                    stats, rows=rows,
+                    minmax=carried_minmax(self.db, view.as_query()))
                 maintained.append(cv.name)
             bases = view.base_tables()
             # replace, never mutate: the old entry object may still be

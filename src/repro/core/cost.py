@@ -201,6 +201,23 @@ def view_stats_from_estimate(est: QueryEstimate) -> TableStats:
                       width=est.width, estimated=True)
 
 
+def carried_minmax(db: Database,
+                   query: JoinQuery) -> Dict[str, Tuple[int, int]]:
+    """The range of each view column ``"<alias>.<col>"``: its base column's.
+
+    A scan filter or a join only drops rows, so the base table's min/max
+    bound the values the view carries (the pipeline's range probes read
+    them; a view's own stats are estimated).
+    """
+    out: Dict[str, Tuple[int, int]] = {}
+    for r in query.relations:
+        st = db.stats.get(r.table)
+        if st is not None:
+            for c, mm in st.minmax.items():
+                out[f"{r.alias}.{c}"] = mm
+    return out
+
+
 def view_cost(est: QueryEstimate) -> float:
     """Join(V) + A_D * N_P(V) of Eq 5 (+ materialization operator floor)."""
     return est.cost + A_D * est.rows * est.width * 4.0 + C_FIXED

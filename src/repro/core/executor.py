@@ -7,12 +7,17 @@ system picks the join order.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 
 from repro import obs
-from repro.core.cost import estimate_query, view_stats_from_estimate
+from repro.core.cost import (
+    carried_minmax,
+    estimate_query,
+    view_stats_from_estimate,
+)
 from repro.core.database import Database
 from repro.core.jsoj import MergedQuery, shared_query
 from repro.core.model import (
@@ -173,5 +178,7 @@ def ensure_view(db: Database, name: str, query: JoinQuery,
             result = execute_query(db, query)
         else:
             result = compiler.run_query(db, query)
-        db.add_view(name, result, view_stats_from_estimate(est))
+        stats = dataclasses.replace(view_stats_from_estimate(est),
+                                    minmax=carried_minmax(db, query))
+        db.add_view(name, result, stats)
     return True

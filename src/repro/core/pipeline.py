@@ -28,8 +28,15 @@ it:
   :func:`repro.kernels.ops.resolve_use_kernel`) each join prunes probe rows
   through a ``bloom`` semi-join prefilter before the capacity expansion;
   the ``sorted_probe`` join probe runs only when ``use_kernel=True`` forces
-  it (the TPU default probes with XLA ``searchsorted``); off-TPU the jnp
-  reference paths are used.
+  it; off-TPU the jnp reference paths are used.
+* **Range probes** — a step whose build key's values lie in a span not
+  much larger than the step's arrays (by ``TableStats.minmax``; a view's
+  columns carry their base columns' ranges) finds each probe key's
+  matches with one gather from a table of prefix counts over that span
+  (:func:`repro.relational.join.join_with_capacity`'s ``key_range``);
+  every other step bisects with XLA ``searchsorted``.  A build key that
+  falls outside the planned range (stats gone stale) is counted on the
+  device, and the step re-runs by bisection.
 
 Bag semantics are identical to the eager path (the parity contract tested
 in ``tests/test_pipeline.py``): capacities only change padding, never the
@@ -62,9 +69,11 @@ from repro.core.model import JoinQuery, join_schedule, query_signature
 from repro.kernels.ops import bloom_bits_for, resolve_use_kernel
 from repro.relational import Table, dedup
 from repro.relational.join import (
+    KeyRange,
     _round_capacity,
     join_with_capacity,
     left_outer_with_capacity,
+    probe_tally,
 )
 
 # Safety factor applied to cardinality estimates before pow-2 bucketing;
@@ -279,6 +288,8 @@ class UnitProgram:
     ``sizing`` says how each was chosen: ``"probe_bound"`` steps hold their
     probe side's static capacity (the build key is unique), ``"estimate"``
     steps a power of two from the cost model or an overflow retry.
+    ``ranges`` holds each step's ``(kmin, span)`` range probe, or ``None``
+    where the step bisects (empty: every step bisects).
     """
 
     kind: str
@@ -289,6 +300,11 @@ class UnitProgram:
     signature: object                     # hashable cache identity
     est_rows: Tuple[float, ...] = ()      # cost-model rows per join step
     sizing: Tuple[str, ...] = ()          # ESTIMATE | PROBE_BOUND per step
+    ranges: Tuple[Optional[KeyRange], ...] = ()
+
+    def step_ranges(self) -> Tuple[Optional[KeyRange], ...]:
+        """One ``(kmin, span)`` or ``None`` per step."""
+        return self.ranges or (None,) * len(self.capacities)
 
 
 # ---------------------------------------------------------------------------
@@ -297,6 +313,12 @@ class UnitProgram:
 
 ESTIMATE = "estimate"
 PROBE_BOUND = "probe_bound"
+
+# A step probes through a table of prefix counts over its build key's range
+# where that range spans at most this many times the larger of the build
+# rows and the step's probe capacity: the table then costs no more memory
+# than a few of the arrays the step already holds.
+RANGE_SPAN_FACTOR = 4
 
 
 def _bucket(rows: float, margin: float, clamp: Optional[int]) -> int:
@@ -371,30 +393,51 @@ def probe_capacities(db: Database,
                  _step_sides(program.kind, program.unit, program.orders))
 
 
+def _key_range(st, col: str,
+               probe_cap: Optional[int]) -> Optional[KeyRange]:
+    """``(kmin, span)`` of a build column by its stats, or ``None``.
+
+    ``span`` is the pow-2 bucket above ``max - min`` (so small growth keeps
+    the executable), taken only where it is at most
+    :data:`RANGE_SPAN_FACTOR` times the larger of the build rows and the
+    probe capacity; sparse keys and columns without a ``minmax`` bisect.
+    """
+    mm = st.minmax.get(col) if st is not None else None
+    if mm is None:
+        return None
+    span = _round_capacity(mm[1] - mm[0])
+    if span > RANGE_SPAN_FACTOR * max(st.rows, probe_cap or 0):
+        return None
+    return (int(mm[0]), span)
+
+
 def _size_steps(db: Database, kind: str, unit, orders, rows: Sequence[float],
                 margin: float, clamp: Optional[int]):
-    """``(capacities, sizing)`` of a freshly planned unit.
+    """``(capacities, sizing, ranges)`` of a freshly planned unit.
 
     A step whose build key is unique by counted stats matches each probe
     row at most once, so its probe side's capacity bounds it: it takes
     ``min(estimate bucket, probe capacity)`` and is ``PROBE_BOUND`` where
     the probe side is the smaller.  Every other step keeps the estimate
-    bucket.  No capacity grows under the rule.
+    bucket.  No capacity grows under the rule.  Each step's range probe is
+    :func:`_key_range`'s.
     """
     caps: List[int] = []
     sizing: List[str] = []
+    ranges: List[Optional[KeyRange]] = []
     for r, (build, probe) in zip(rows, _step_sides(kind, unit, orders)):
         cap = _bucket(r, margin, clamp)
         st = db.stats.get(build[0]) if build else None
-        bound = (_probe_capacity(db, probe, caps)
-                 if st is not None and st.unique(build[1]) else None)
+        probe_cap = _probe_capacity(db, probe, caps)
+        ranges.append(_key_range(st, build[1], probe_cap) if build else None)
+        bound = probe_cap if st is not None and st.unique(build[1]) else None
         if bound and bound <= cap:
             caps.append(bound)
             sizing.append(PROBE_BOUND)
         else:
             caps.append(cap)
             sizing.append(ESTIMATE)
-    return tuple(caps), tuple(sizing)
+    return tuple(caps), tuple(sizing), tuple(ranges)
 
 
 def _bind(db: Database, prog: "UnitProgram") -> "UnitProgram":
@@ -433,7 +476,8 @@ def build_query_program(
     orders = (est.order,)
     rows = tuple(step_expansions(db, query, est.order))
     kind = "edges" if edges else "query"
-    caps, sizing = _size_steps(db, kind, query, orders, rows, margin, clamp)
+    caps, sizing, ranges = _size_steps(db, kind, query, orders, rows, margin,
+                                       clamp)
     return UnitProgram(
         kind=kind,
         unit=query,
@@ -443,6 +487,7 @@ def build_query_program(
         signature=("q", query_signature(query), edges),
         est_rows=rows,
         sizing=sizing,
+        ranges=ranges,
     )
 
 
@@ -489,8 +534,8 @@ def build_merged_program(
         # unmatched left rows also occupy slots (counts = max(match, 1))
         cap_rows.append(rows * max(1.0, b_rel.rows * sel_first) + rows)
         rows *= max(1.0, b_rel.rows * sel_all)
-    caps, sizing = _size_steps(db, "merged", merged, tuple(orders), cap_rows,
-                               margin, clamp)
+    caps, sizing, ranges = _size_steps(db, "merged", merged, tuple(orders),
+                                       cap_rows, margin, clamp)
     return UnitProgram(
         kind="merged",
         unit=merged,
@@ -500,6 +545,7 @@ def build_merged_program(
         signature=("m", merged),
         est_rows=tuple(cap_rows),
         sizing=sizing,
+        ranges=ranges,
     )
 
 
@@ -555,7 +601,7 @@ def _traced_query(
     tables: Dict[str, Table],
     query: JoinQuery,
     order: Sequence[str],
-    caps_iter,
+    steps,
     totals: List[jax.Array],
     prefilter: List[jax.Array],
     use_kernel: bool,
@@ -566,33 +612,44 @@ def _traced_query(
 
     Same schedule as :func:`executor.execute_query` — both walk
     :func:`repro.core.model.join_schedule`, which is what keeps the
-    pre-planned capacities aligned with the joins actually traced.  Each
-    step runs under a ``jax.named_scope`` (``scan:<alias>``,
-    ``join:<alias>``), which the compiled HLO keeps in its ops' metadata.
+    pre-planned capacities aligned with the joins actually traced.
+    ``steps`` yields each step's ``(capacity, key_range)``; each step adds
+    its :func:`_step_total` to ``totals``.  Each step runs under a
+    ``jax.named_scope`` (``scan:<alias>``, ``join:<alias>``), which the
+    compiled HLO keeps in its ops' metadata.
     """
     cur = _scan(tables, query.relation(order[0]), needed)
     for alias, conds, closing in join_schedule(query, order):
         nxt = _scan(tables, query.relation(alias), needed)
         on = [qualified_cond(c, alias) for c in conds]
+        capacity, key_range = next(steps)
         with jax.named_scope(f"join:{alias}"):
+            tally = probe_tally(cur, nxt, on, key_range)
             cur, required, counts = join_with_capacity(
-                cur, nxt, on, how="inner", capacity=next(caps_iter),
+                cur, nxt, on, how="inner", capacity=capacity,
                 use_kernel=use_kernel,
-                bloom_bits=bloom_bits_for(nxt.capacity) if use_bloom else 0)
+                bloom_bits=bloom_bits_for(nxt.capacity) if use_bloom else 0,
+                key_range=key_range)
             for c in closing:
                 cur = cur.mask(cur[f"{c.left}.{c.lcol}"]
                                == cur[f"{c.right}.{c.rcol}"])
-        totals.append(required)
+        totals.append(_step_total(required, tally))
         if counts is not None:
             prefilter.append(counts)
     return cur
+
+
+def _step_total(required: jax.Array, tally: jax.Array) -> jax.Array:
+    """A join step's int32 ``(required rows, probe rows with a key, build
+    keys outside its range)``: what the host reads of it per attempt."""
+    return jnp.concatenate([required.astype(jnp.int32).reshape(1), tally])
 
 
 def _traced_merged(
     tables: Dict[str, Table],
     merged: MergedQuery,
     orders: Sequence[Tuple[str, ...]],
-    caps_iter,
+    steps,
     totals: List[jax.Array],
     prefilter: List[jax.Array],
     use_kernel: bool,
@@ -605,7 +662,7 @@ def _traced_merged(
     member's row filter, dedup and edge projection.
     """
     needed = _needed_columns_merged(merged)
-    cur = _traced_query(tables, shared_query(merged), orders[0], caps_iter,
+    cur = _traced_query(tables, shared_query(merged), orders[0], steps,
                         totals, prefilter, use_kernel, use_bloom, needed)
     cur = cur.with_columns(
         __srow__=jnp.arange(cur.capacity, dtype=jnp.int32))
@@ -624,7 +681,7 @@ def _traced_merged(
             continue
         if len(b.relations) > 1:
             branch_tbl = _traced_query(tables, b.as_query(), orders[1 + bi],
-                                       caps_iter, totals, prefilter,
+                                       steps, totals, prefilter,
                                        use_kernel, use_bloom, needed)
         else:
             branch_tbl = _scan(tables, b.relations[0], needed)
@@ -635,12 +692,15 @@ def _traced_merged(
                 **{brow: jnp.arange(branch_tbl.capacity, dtype=jnp.int32)})
             on = [(f"{c.left}.{c.lcol}", f"{c.right}.{c.rcol}")
                   for c in b.link_conds]
+            capacity, key_range = next(steps)
+            tally = probe_tally(cur, branch_tbl, on, key_range)
             cur, required, counts = left_outer_with_capacity(
-                cur, branch_tbl, on, ind, capacity=next(caps_iter),
+                cur, branch_tbl, on, ind, capacity=capacity,
                 use_kernel=use_kernel,
                 bloom_bits=bloom_bits_for(branch_tbl.capacity)
-                if use_bloom else 0)
-        totals.append(required)
+                if use_bloom else 0,
+                key_range=key_range)
+        totals.append(_step_total(required, tally))
         if counts is not None:
             prefilter.append(counts)
 
@@ -665,7 +725,8 @@ def _traced_merged(
 def _stack_totals(totals: List[jax.Array],
                   prefilter: List[jax.Array]) -> jax.Array:
     """The one vector the host syncs per attempt: each join step's
-    required rows, then each Bloom prefilter's (probed, passed) pair."""
+    :func:`_step_total` triple, then each Bloom prefilter's (probed,
+    passed) pair."""
     parts = [t.astype(jnp.int32).reshape(-1) for t in totals + prefilter]
     if not parts:
         return jnp.zeros((0,), jnp.int32)
@@ -732,13 +793,16 @@ def op_scopes() -> Dict[str, Dict[str, str]]:
 
 
 def _make_fn(program: UnitProgram, use_kernel: bool, use_bloom: bool):
+    def steps():
+        return iter(zip(program.capacities, program.step_ranges()))
+
     if program.kind == "merged":
         def fn(tables):
             totals: List[jax.Array] = []
             prefilter: List[jax.Array] = []
             edges = _traced_merged(tables, program.unit, program.orders,
-                                   iter(program.capacities), totals,
-                                   prefilter, use_kernel, use_bloom)
+                                   steps(), totals, prefilter, use_kernel,
+                                   use_bloom)
             return edges, _stack_totals(totals, prefilter)
     else:
         # views ("query") keep every column — later queries are rewritten
@@ -751,8 +815,8 @@ def _make_fn(program: UnitProgram, use_kernel: bool, use_bloom: bool):
             totals: List[jax.Array] = []
             prefilter: List[jax.Array] = []
             res = _traced_query(tables, program.unit, program.orders[0],
-                                iter(program.capacities), totals, prefilter,
-                                use_kernel, use_bloom, needed)
+                                steps(), totals, prefilter, use_kernel,
+                                use_bloom, needed)
             if program.kind == "edges":
                 res = edge_output(res, program.unit.src, program.unit.dst)
             return res, _stack_totals(totals, prefilter)
@@ -778,6 +842,14 @@ def _bloom_counter(outcome: str):
         help="Probe rows with a key before (probed) and after (passed) the "
              "Bloom semi-join prefilter.",
         outcome=outcome)
+
+
+def _probe_counter(method: str):
+    return obs.REGISTRY.counter(
+        "pipeline_probe_rows_total",
+        help="Probe rows with a key of the kept attempts' join steps, by "
+             "how the step found their matches (range table or search).",
+        method=method)
 
 
 def _sizing_counter(sizing: str):
@@ -870,6 +942,8 @@ class PipelineCompiler:
         # never ran a pipeline
         for outcome in ("probed", "passed"):
             _bloom_counter(outcome)
+        for method in ("range", "search"):
+            _probe_counter(method)
 
     _EVENT_METRIC = "pipeline_executable_events_total"
 
@@ -921,11 +995,15 @@ class PipelineCompiler:
 
     def _build(self, db: Database, kind: str, unit) -> UnitProgram:
         if kind == "merged":
-            return build_merged_program(db, unit, self.margin,
+            prog = build_merged_program(db, unit, self.margin,
                                         self.initial_capacity_clamp)
-        return build_query_program(db, unit, edges=(kind == "edges"),
-                                   margin=self.margin,
-                                   clamp=self.initial_capacity_clamp)
+        else:
+            prog = build_query_program(db, unit, edges=(kind == "edges"),
+                                       margin=self.margin,
+                                       clamp=self.initial_capacity_clamp)
+        if self.probe_kernel:            # the forced kernel probes every step
+            prog = dataclasses.replace(prog, ranges=())
+        return prog
 
     def _program(self, db: Database, kind: str, unit):
         """``(pkey, program)``: the stats-keyed program, else the memo's,
@@ -953,7 +1031,7 @@ class PipelineCompiler:
         return pkey, _bind(db, prog)
 
     def _executable(self, prog: UnitProgram, inputs: Dict[str, Table]):
-        key = (prog.signature, prog.orders, prog.capacities,
+        key = (prog.signature, prog.orders, prog.capacities, prog.ranges,
                self.probe_kernel, self.use_bloom, _schema_fp(inputs))
         tiered = (self.tier_compile
                   and max(prog.capacities, default=0) <= TIER_MAX_CAPACITY)
@@ -1042,6 +1120,16 @@ class PipelineCompiler:
         _bloom_counter("probed").inc(probed)
         _bloom_counter("passed").inc(passed)
 
+    @staticmethod
+    def _count_probes(prog: UnitProgram, keyed: np.ndarray) -> None:
+        """Probe rows with a key of one kept attempt's steps, by the probe
+        that ran them (``prog.ranges``).  Host values from the one sync."""
+        rows = {"range": 0, "search": 0}
+        for n, key_range in zip(keyed.tolist(), prog.step_ranges()):
+            rows["search" if key_range is None else "range"] += int(n)
+        for method, n in rows.items():
+            _probe_counter(method).inc(n)
+
     def last_rows(self, signature) -> Optional[Dict[str, list]]:
         """Per-step ``{actual, capacities, est_rows}`` from the most recent
         run of the program with this signature, or ``None`` if it never ran
@@ -1079,15 +1167,15 @@ class PipelineCompiler:
         """Would running this program compile or just launch?
 
         ``"cached"`` — an executable for the exact (signature, orders,
-        capacities, kernel flags, schema) key is resident; ``"uncompiled"``
-        — it would compile on first run; ``"unknown"`` — an input (an
-        unmaterialized view) is missing from ``tables``, so the schema part
-        of the key cannot be formed without executing.
+        capacities, ranges, kernel flags, schema) key is resident;
+        ``"uncompiled"`` — it would compile on first run; ``"unknown"`` —
+        an input (an unmaterialized view) is missing from ``tables``, so
+        the schema part of the key cannot be formed without executing.
         """
         if any(n not in tables for n in prog.inputs):
             return "unknown"
         inputs = {n: tables[n] for n in prog.inputs}
-        key = (prog.signature, prog.orders, prog.capacities,
+        key = (prog.signature, prog.orders, prog.capacities, prog.ranges,
                self.probe_kernel, self.use_bloom, _schema_fp(inputs))
         with _CACHE_LOCK:
             return "cached" if key in _EXECUTABLE_CACHE else "uncompiled"
@@ -1095,17 +1183,22 @@ class PipelineCompiler:
     def _run(self, db: Database, pkey, prog: UnitProgram):
         """Execute with overflow-retry; remembers proven capacities.
 
-        One host sync per attempt (the totals vector).  An overflowed step
-        re-executes at the pow-2 bucket of its *exact* requirement, which at
-        least doubles it; steps downstream of a truncation may only reveal
-        their true requirement on the retry, so the loop runs to a fixpoint
-        (bounded by the step count — each round fixes at least the first
-        overflowing step for good).  A ``PROBE_BOUND`` step that overflows
-        has a build key that is not unique after all: it becomes an
-        ``ESTIMATE`` step at its grown capacity, and the other bound steps
-        follow their probe sides.  The synced vector also carries each
-        Bloom prefilter's (probed, passed) rows, counted on the attempt
-        that is kept (:meth:`_count_prefilter`).
+        One host sync per attempt (the totals vector).  A range-probed
+        step that found build keys outside its range (stats gone stale
+        since the program was planned) may have matched wrongly: it
+        switches to the bisection for good and the unit re-executes, its
+        capacities as they were (the counts of that attempt may be wrong
+        too).  An overflowed step re-executes at the pow-2 bucket of its
+        *exact* requirement, which at least doubles it; steps downstream of
+        a truncation may only reveal their true requirement on the retry,
+        so the loop runs to a fixpoint (bounded by the step count — each
+        round fixes at least the first overflowing step for good).  A
+        ``PROBE_BOUND`` step that overflows has a build key that is not
+        unique after all: it becomes an ``ESTIMATE`` step at its grown
+        capacity, and the other bound steps follow their probe sides.  The synced vector also carries each
+        step's probe rows with a key and each Bloom prefilter's (probed,
+        passed) rows, counted on the attempt that is kept
+        (:meth:`_count_probes`, :meth:`_count_prefilter`).
         """
         inputs = {n: db.tables[n] for n in prog.inputs}
         cur = prog
@@ -1121,10 +1214,20 @@ class PipelineCompiler:
             # the host only blocks here while the device runs the unit
             with obs.span("pipeline.wait", category="execute", detail=True):
                 synced = np.asarray(totals)           # the one host sync
-            need, prefilter = synced[:len(caps)], synced[len(caps):]
+            need, keyed, outside = synced[:3 * len(caps)].reshape(-1, 3).T
+            prefilter = synced[3 * len(caps):]
+            stale = [r is not None and int(n) > 0
+                     for r, n in zip(cur.step_ranges(), outside.tolist())]
+            if any(stale):
+                self._bump("retries")
+                cur = dataclasses.replace(cur, ranges=tuple(
+                    None if st else r
+                    for r, st in zip(cur.step_ranges(), stale)))
+                continue
             if need.size == 0 or bool(
                     (need <= np.asarray(caps, dtype=np.int64)).all()):
                 self._observe_rows(cur, need)
+                self._count_probes(cur, keyed)
                 self._count_prefilter(prefilter)
                 if cur is not prog:
                     with self._lock:                  # skip retries next time

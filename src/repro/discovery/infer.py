@@ -191,7 +191,9 @@ class ContainmentChecker:
     query object in, so the compiler's unit memo pins one program and
     executables are shared across all checks whose build sides land in the
     same capacity bucket.  Probe/build tables are cached per column, so a
-    child column checked against three parents samples once.
+    child column checked against three parents samples once.  Their stats
+    carry no min/max: the pinned program would keep the first check's key
+    range for every later build side, so every check bisects.
     """
 
     QUERY = JoinQuery(
@@ -231,8 +233,7 @@ class ContainmentChecker:
                 capacity=round_capacity(self.sample),
                 k=vals.astype(np.int32))
             stats = TableStats(
-                rows=n, distinct={"k": int(np.unique(vals).size)}, width=1,
-                minmax={"k": (int(vals.min()), int(vals.max()))} if n else {})
+                rows=n, distinct={"k": int(np.unique(vals).size)}, width=1)
             self._probes[key] = (probe, stats, n)
         return self._probes[key]
 
@@ -244,9 +245,7 @@ class ContainmentChecker:
             build = Table.from_arrays(
                 capacity=round_capacity(max(1, n)),
                 v=vals.astype(np.int32))
-            stats = TableStats(
-                rows=n, distinct={"v": n}, width=1,
-                minmax={"v": (int(vals.min()), int(vals.max()))} if n else {})
+            stats = TableStats(rows=n, distinct={"v": n}, width=1)
             self._builds[key] = (build, stats)
         return self._builds[key]
 

@@ -28,8 +28,7 @@ def resolve_use_kernel(use_kernel=None, *, probe: bool = False) -> bool:
 
     The join probe (``probe=True``) is the exception: ``sorted_probe``
     ranks by counting, work that grows with probe x build rows, so the TPU
-    default probes with XLA ``searchsorted`` and only ``True`` selects the
-    kernel.
+    default probes in XLA and only ``True`` selects the kernel.
     """
     if use_kernel is not None:
         return bool(use_kernel)

@@ -13,7 +13,8 @@ step adds the lane-parallel comparison counts of one (probe tile, build
 block) pair into the output block (the accumulate pattern of
 ``segment_csr``).  It compiles at any build size, but its work grows with
 probe x build rows, so :func:`repro.kernels.ops.resolve_use_kernel` leaves
-it off the TPU default: the join probe there runs XLA ``searchsorted``.
+it off the TPU default: the join probe there runs in XLA (a range table
+or ``searchsorted``, see :mod:`repro.relational.join`).
 """
 from __future__ import annotations
 

@@ -10,6 +10,10 @@ wrong primitive; we instead evaluate every join as
 which maps onto the VPU (bitonic sorts, vectorized binary search) and keeps
 every shape static.  ``N``-to-``N`` joins are handled exactly: each left row
 expands into ``hi - lo`` output rows via a cumsum/searchsorted expansion.
+Where the caller knows the build keys lie in a bounded range (the compiled
+pipeline, from ANALYZE's min/max), the probe reads ``lo`` and ``hi`` from a
+table of prefix counts over that range in one gather, in place of the
+binary search's passes; the ranges it returns are the search's.
 
 Outer-join semantics follow Theorem 4.3 of the paper: a left row with no
 match emits exactly one output row whose right side is *null*, signalled by
@@ -106,22 +110,82 @@ def join_count(
     return jnp.sum(counts)
 
 
-def _probe_ranges(rk_sorted: jax.Array, lk: jax.Array, use_kernel: bool):
-    """(lo, hi) match ranges; Pallas ``sorted_probe`` or jnp bisection.
+KeyRange = Tuple[int, int]   # (kmin, span): build keys in [kmin, kmin + span)
 
-    The jnp path runs a single bisection over ``[lk, lk + 1]``: keys are
-    int32, so ``side="right"`` of ``k`` equals ``side="left"`` of ``k + 1``.
-    The only key that wraps is ``NULL_KEY64`` (int32 max), whose rows are
+
+def _range_slot(k: jax.Array, key_range: KeyRange) -> jax.Array:
+    """``k - kmin`` clipped to ``[0, span]``, with no int32 overflow: the
+    clip comes first, so the difference always fits."""
+    kmin, span = key_range
+    kend = min(kmin + span, int(NULL_KEY64))
+    return jnp.clip(k, kmin, kend) - jnp.int32(kmin)
+
+
+def _range_probe(rk: jax.Array, lk: jax.Array, key_range: KeyRange):
+    """(lo, hi) match ranges from one table of prefix counts.
+
+    ``below[s]`` counts the build keys under ``kmin + s`` (a scatter of the
+    keys into ``span`` slots, then a cumulative sum), so a probe key's
+    ``searchsorted(side="left")`` is one gather at its clipped slot: keys
+    under ``kmin`` read 0, keys at or over ``kmin + span`` (``NULL_KEY64``
+    among them) read every build key, and ``lk + 1`` of ``NULL_KEY64``
+    wraps to int32 min, which reads 0 as the bisection's does.  Exact only
+    where every valid build key lies in the range: :func:`probe_tally`
+    counts those that do not.
+    """
+    span = key_range[1]
+    keyed = (rk != NULL_KEY64).astype(jnp.int32)
+    mark = jnp.zeros((span + 2,), jnp.int32)
+    mark = mark.at[_range_slot(rk, key_range) + 1].add(keyed)
+    below = jnp.cumsum(mark[:span + 1])
+    n = lk.shape[0]
+    pos = below[_range_slot(jnp.concatenate([lk, lk + 1]), key_range)]
+    return pos[:n], pos[n:]
+
+
+def _probe_ranges(rk_sorted: jax.Array, lk: jax.Array, use_kernel: bool,
+                  rk: Optional[jax.Array] = None,
+                  key_range: Optional[KeyRange] = None):
+    """(lo, hi) match ranges; Pallas ``sorted_probe``, the range table of
+    :func:`_range_probe` where ``key_range`` bounds the build keys ``rk``,
+    else jnp bisection.
+
+    The bisection runs once over ``[lk, lk + 1]``: keys are int32, so
+    ``side="right"`` of ``k`` equals ``side="left"`` of ``k + 1``.  The
+    only key that wraps is ``NULL_KEY64`` (int32 max), whose rows are
     masked out of the match counts anyway.
     """
     if use_kernel:
         from repro.kernels import ops as kops
 
         return kops.sorted_probe(rk_sorted, lk)
+    if key_range is not None:
+        return _range_probe(rk, lk, key_range)
     n = lk.shape[0]
     pos = jnp.searchsorted(rk_sorted, jnp.concatenate([lk, lk + 1]),
                            side="left")
     return pos[:n], pos[n:]
+
+
+def probe_tally(left: Table, right: Table, on: Sequence[Tuple[str, str]],
+                key_range: Optional[KeyRange] = None) -> jax.Array:
+    """int32 ``(probe rows with a key, valid build keys outside
+    key_range)`` of a join on ``on``'s first condition.
+
+    The second is 0 without a range; where it is not, the range probe of
+    the same join may have matched wrongly, and the caller runs the join
+    again by bisection.
+    """
+    lk = composite_key(left, (on[0][0],))
+    rk = composite_key(right, (on[0][1],))
+    outside = jnp.int32(0)
+    if key_range is not None:
+        kmin, span = key_range
+        out = rk < kmin
+        if kmin + span < int(NULL_KEY64):
+            out = out | (rk >= kmin + span)
+        outside = jnp.sum(out & (rk != NULL_KEY64), dtype=jnp.int32)
+    return jnp.stack([jnp.sum(lk != NULL_KEY64, dtype=jnp.int32), outside])
 
 
 def _join_core(
@@ -133,6 +197,7 @@ def _join_core(
     capacity: int,
     indicator: Optional[str],
     use_kernel: bool,
+    key_range: Optional[KeyRange] = None,
 ) -> Tuple[Table, jax.Array]:
     """Static-capacity pair expansion; returns (table, required_rows).
 
@@ -142,7 +207,7 @@ def _join_core(
     """
     order = jnp.argsort(rk)
     rk_sorted = rk[order]
-    lo, hi = _probe_ranges(rk_sorted, lk, use_kernel)
+    lo, hi = _probe_ranges(rk_sorted, lk, use_kernel, rk, key_range)
     match_counts = jnp.where(left.valid & (lk != NULL_KEY64), hi - lo, 0)
     if how == "inner":
         counts = match_counts
@@ -183,6 +248,7 @@ def join_with_capacity(
     indicator: Optional[str] = None,
     use_kernel: bool = False,
     bloom_bits: int = 0,
+    key_range: Optional[KeyRange] = None,
 ) -> Tuple[Table, jax.Array, Optional[jax.Array]]:
     """Fully-traced join at a static capacity.
 
@@ -198,7 +264,11 @@ def join_with_capacity(
     negatives, so pruning is exact for inner joins and turns outer-join
     prunees into (correct) unmatched null rows.  ``prefilter`` is then the
     int32 pair (probe rows with a key before the filter, those that
-    passed it); ``None`` without a prefilter.
+    passed it); ``None`` without a prefilter.  ``key_range`` ``(kmin,
+    span)`` probes through a table of ``span + 1`` prefix counts instead
+    of a bisection (:func:`_range_probe`): the same ``(lo, hi)``, so the
+    same output, where every valid build key lies in ``[kmin, kmin +
+    span)``; :func:`probe_tally` says whether one does not.
     """
     on = list(on)
     key_on, rest = on[:1], on[1:]
@@ -217,7 +287,7 @@ def join_with_capacity(
         prefilter = jnp.stack([probed,
                                jnp.sum(lk != NULL_KEY64, dtype=jnp.int32)])
     out, total = _join_core(left, right, lk, rk, how, capacity, indicator,
-                            use_kernel)
+                            use_kernel, key_range)
     for lcol, rcol in rest:
         keep = out[lcol] == out[rcol]
         if how == "left_outer" and indicator is not None:
@@ -236,6 +306,7 @@ def left_outer_with_capacity(
     capacity: int,
     use_kernel: bool = False,
     bloom_bits: int = 0,
+    key_range: Optional[KeyRange] = None,
 ) -> Tuple[Table, jax.Array, Optional[jax.Array]]:
     """Traced exact left-outer join at static capacity; returns
     ``(table, required, prefilter)`` as :func:`join_with_capacity` does.
@@ -251,13 +322,13 @@ def left_outer_with_capacity(
         return join_with_capacity(
             left, right, on, how="left_outer", capacity=capacity,
             indicator=indicator, use_kernel=use_kernel,
-            bloom_bits=bloom_bits)
+            bloom_bits=bloom_bits, key_range=key_range)
     rowid = "__rowid__"
     lt = left.with_columns(**{rowid: jnp.arange(left.capacity,
                                                 dtype=jnp.int32)})
     inner, total, prefilter = join_with_capacity(
         lt, right, on, how="inner", capacity=capacity,
-        use_kernel=use_kernel, bloom_bits=bloom_bits)
+        use_kernel=use_kernel, bloom_bits=bloom_bits, key_range=key_range)
     hits = jnp.zeros((left.capacity,), dtype=jnp.int32)
     hits = hits.at[inner[rowid]].add(inner.valid.astype(jnp.int32))
     unmatched = left.valid & (hits == 0)

@@ -29,6 +29,7 @@ from repro.core import (
     extract_graph,
     query_signature,
 )
+from repro.core.pipeline import clear_executable_cache
 from repro.data import make_tpcds, recommendation_model
 from repro.data.tpcds import buy_query, fraud_model
 from repro.relational import Table
@@ -166,6 +167,9 @@ def db():
 
 
 def test_engine_caches_and_matches_wrapper(db):
+    # the executable store is process-wide: start it empty, so that an
+    # earlier test of the same workload cannot turn the cold misses to hits
+    clear_executable_cache()
     engine = ExtractionEngine(db)
     model = recommendation_model("store")
 

@@ -460,6 +460,33 @@ def test_refresh_parity_kernel_and_bloom_path():
     assert _graph_digests(r.graph) == _graph_digests(oracle.graph)
 
 
+def test_refresh_after_inserts_beyond_the_planned_key_ranges():
+    """Items keyed beyond every range the programs planned, and sales of
+    them: each delta refresh is exact.  The second round's delta terms
+    run the programs memoized in the first, whose ranges cover the first
+    round's keys only: their stale steps re-run by bisection."""
+    db = make_tpcds(sf=1, seed=0)
+    compiler = PipelineCompiler()
+    engine = ExtractionEngine(db, compiler=compiler, auto_refresh=True)
+    model = fraud_model("store")
+    engine.extract(model)
+
+    def one(v):
+        return np.array([v], np.int32)
+
+    for _ in range(2):
+        key = db.stats["item"].minmax["i_id"][1] + 3000
+        rid = db.stats["store_sales"].minmax["rid"][1] + 1
+        db.insert_rows("item", rid=one(key), i_id=one(key), i_price=one(1))
+        db.insert_rows("store_sales", rid=one(rid), c_sk=one(3),
+                       i_sk=one(key), p_sk=one(0), o_sk=one(1))
+        r = engine.extract(model)
+        assert r.refresh.path == "delta"
+        assert _graph_digests(r.graph) == \
+            _graph_digests(_oracle(db, model).graph)
+    assert compiler.stats["retries"] > 0
+
+
 def test_refresh_paths_noop_threshold_and_fallbacks():
     db = make_tpcds(sf=1, seed=0)
     engine = ExtractionEngine(db, refresh_threshold=0.05)

@@ -3,6 +3,7 @@
 Hypothesis property tests live in test_properties.py (optional dep).
 """
 import numpy as np
+import pytest
 
 from repro.relational import (
     Table,
@@ -143,3 +144,113 @@ def test_bloom_prefilter_counts_match_numpy():
         left, right, [("L.k", "R.k")], capacity=4096)
     assert none is None
     assert int(required_off) == int(required)
+
+
+I32_MIN, I32_MAX = -2**31, 2**31 - 1
+
+
+def _range_case(lk, rk, lvalid=None, rvalid=None, key_range=None):
+    lk, rk = np.asarray(lk, np.int64), np.asarray(rk, np.int64)
+    lvalid = np.ones(lk.shape, bool) if lvalid is None else np.asarray(lvalid)
+    rvalid = np.ones(rk.shape, bool) if rvalid is None else np.asarray(rvalid)
+    if key_range is None:                     # as the pipeline plans it
+        from repro.relational.join import round_capacity
+
+        live = rk[rvalid]
+        key_range = (int(live.min()), round_capacity(int(np.ptp(live))))
+    return lk.astype(np.int32), lvalid, rk.astype(np.int32), rvalid, key_range
+
+
+_RANGE_CASES = {
+    "unique_dense": _range_case(np.arange(40) % 23, np.arange(1, 21)),
+    "non_unique": _range_case([3, 4, 4, 9, 1, 7, 7, 7],
+                              [4, 4, 7, 3, 3, 3, 9, 4, 8]),
+    "nulls_either_side": _range_case(
+        [5, 6, 7, 8, 5, I32_MAX], [5, 6, 7, 9, 6],
+        lvalid=[True, False, True, True, True, True],
+        rvalid=[True, True, False, True, True]),
+    "probe_outside_range": _range_case([-100, 0, 1, 2, 3, 10, 11, 999],
+                                       [1, 2, 2, 3, 10]),
+    "negative": _range_case([-9, -5, -5, -1, 0, 3], [-7, -5, -5, -3, 0]),
+    "int32_min": _range_case(
+        [I32_MIN, I32_MIN + 1, I32_MIN + 4, I32_MAX, 0, I32_MAX - 1],
+        [I32_MIN, I32_MIN, I32_MIN + 4, I32_MIN + 2]),
+    "int32_max": _range_case([I32_MAX - 1, I32_MAX, I32_MAX - 5, 3, I32_MIN],
+                             [I32_MAX - 1, I32_MAX - 3, I32_MAX - 1]),
+    "all_invalid_build": _range_case([1, 2, 3], [1, 2, 3],
+                                     rvalid=[False] * 3, key_range=(0, 8)),
+    "span_of_one": _range_case([4, 5, 6, 5, I32_MAX], [5, 5, 5],
+                               key_range=(5, 1)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_RANGE_CASES))
+def test_range_probe_equals_bisection(case):
+    """The range table returns the bisection's (lo, hi) for every probe
+    key, so the traced joins' whole output tables are bit-identical:
+    inner, native left outer, and left outer with a post-filter
+    condition."""
+    import jax.numpy as jnp
+
+    from repro.relational.join import (
+        _probe_ranges,
+        composite_key,
+        join_with_capacity,
+        left_outer_with_capacity,
+        probe_tally,
+    )
+
+    lk, lvalid, rk, rvalid, key_range = _RANGE_CASES[case]
+    left = Table.from_arrays(k=lk, a=np.arange(lk.size, dtype=np.int32),
+                             z=lk % 3).mask(lvalid).prefix("L")
+    right = Table.from_arrays(k=rk, b=np.arange(100, 100 + rk.size,
+                                                dtype=np.int32),
+                              z=rk % 2).mask(rvalid).prefix("R")
+    lkey = composite_key(left, ("L.k",))
+    rkey = composite_key(right, ("R.k",))
+    rk_sorted = jnp.sort(rkey)
+    want = _probe_ranges(rk_sorted, lkey, False)
+    got = _probe_ranges(rk_sorted, lkey, False, rkey, key_range)
+    for w, g in zip(want, got):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    probed, outside = (int(x) for x in np.asarray(
+        probe_tally(left, right, [("L.k", "R.k")], key_range)))
+    assert probed == int((lvalid & (lk != I32_MAX)).sum())
+    assert outside == 0
+
+    def same(a, b):
+        assert a.column_names() == b.column_names()
+        for name in a.column_names():
+            np.testing.assert_array_equal(np.asarray(a[name]),
+                                          np.asarray(b[name]), name)
+        np.testing.assert_array_equal(np.asarray(a.valid),
+                                      np.asarray(b.valid))
+
+    on = [("L.k", "R.k")]
+    joins = [
+        lambda kr: join_with_capacity(left, right, on, capacity=64,
+                                      key_range=kr),
+        lambda kr: left_outer_with_capacity(left, right, on, "m", 64,
+                                            key_range=kr),
+        lambda kr: left_outer_with_capacity(left, right,
+                                            on + [("L.z", "R.z")], "m", 64,
+                                            key_range=kr),
+    ]
+    for join in joins:
+        (t_range, n_range, _), (t_search, n_search, _) = join(key_range), \
+            join(None)
+        assert int(n_range) == int(n_search)
+        same(t_range, t_search)
+
+
+def test_probe_tally_counts_build_keys_outside_the_range():
+    from repro.relational.join import probe_tally
+
+    left = Table.from_arrays(k=np.array([1, 2, I32_MAX], np.int32))
+    right = Table.from_arrays(k=np.array([0, 1, 5, 8, 9, I32_MAX], np.int32))
+    right = right.mask(np.array([True, True, True, True, False, True]))
+    tally = probe_tally(left, right, [("k", "k")], (1, 7))
+    # 0 is under kmin and 8 at kmin + span; 9 is invalid, I32_MAX a NULL
+    assert np.asarray(tally).tolist() == [2, 2]
+    assert np.asarray(probe_tally(left, right, [("k", "k")])).tolist() \
+        == [2, 0]
