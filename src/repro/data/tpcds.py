@@ -5,6 +5,9 @@ paper's *ratios* with absolute row counts scaled down by 1000x ("SF 10"
 here = 28.8k fact rows), so ``make_tpcds(sf=1000)`` has SF=1's published
 fact counts (2.88M / 1.44M / 0.72M sales rows).  Skew follows TPC-DS: fact
 foreign keys are drawn from a truncated Zipf so hot items/customers exist.
+It is CPU test data, not a benchmark source: it scales TPC-DS down 1000x
+and clamps its Zipf draw, where the chip benchmark's ``bench/`` generates
+dsdgen's shapes at the spec's row counts.
 
 Tables (per sales channel c in {store, catalog, web}):
   customer(rid, c_id, c_prop)            dimension

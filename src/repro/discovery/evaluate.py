@@ -6,8 +6,7 @@ sorted order) so nothing in the schema says which column references which
 ``rename_query`` maps a hand-written query through the same renaming so
 its alias-independent :func:`query_signature` can be compared against
 discovered edge candidates, and ``edge_recovery`` /
-``precision_recall`` turn that into the numbers
-``BENCH_discovery.json`` reports.
+``precision_recall`` turn that into recovery scores.
 
 Scoring is *equivalence-aware*: the synthetic dims carry a surrogate
 ``rid`` that is bit-identical to the id column, and no data-driven method

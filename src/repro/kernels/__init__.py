@@ -1,3 +1,7 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels of the extraction and analytics hot paths.
+
+One module per kernel (``bloom``, ``sorted_probe``, ``segment_csr``,
+``spmv``, ``label_prop``, ``frontier``); :mod:`repro.kernels.ops` holds the
+jitted entry points and the kernel-vs-reference policy, and
+:mod:`repro.kernels.ref` the pure-jnp oracle each kernel is tested against.
+"""

@@ -5,7 +5,6 @@ Each function is the semantic reference the kernels are tested against
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 
@@ -88,24 +87,3 @@ def bloom_probe(bits: jnp.ndarray, keys: jnp.ndarray,
         hit = hit & (bits[pos[i]] > 0)
     return hit
 
-
-def flash_attention(q, k, v, causal: bool = True, window=None):
-    """Dense GQA attention oracle for the flash kernel (no positions arg:
-    q/k indices ARE the positions, matching the kernel's iota masks)."""
-    b, sq, hq, dh = q.shape
-    sk, hkv = k.shape[1], k.shape[2]
-    g = hq // hkv
-    qf = q.astype(jnp.float32) / jnp.sqrt(dh)
-    qf = qf.reshape(b, sq, hkv, g, dh)
-    s = jnp.einsum("bqhgd,bkhd->bhgqk", qf, k.astype(jnp.float32))
-    qpos = jnp.arange(sq)[:, None]
-    kpos = jnp.arange(sk)[None, :]
-    mask = jnp.ones((sq, sk), bool)
-    if causal:
-        mask &= qpos >= kpos
-    if window is not None:
-        mask &= (qpos - kpos) < window
-    s = jnp.where(mask[None, None, None], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", p, v.astype(jnp.float32))
-    return out.reshape(b, sq, hq, dh).astype(q.dtype)

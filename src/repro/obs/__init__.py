@@ -69,10 +69,8 @@ __all__ = [
 def traced_call(name: str, fn, *args, category: str = "", **attrs):
     """Run ``fn()`` under a fresh root span; return ``(result, breakdown)``.
 
-    The benchmark helper: the breakdown dict carries wall/plan/compile/
-    execute/transfer/csr/queue/other seconds plus attribution coverage,
-    and lands in ``BENCH_*.json`` records (asserted by the CI bench-smoke
-    job).
+    The breakdown dict carries wall/plan/compile/execute/transfer/csr/
+    queue/other seconds plus attribution coverage.
     """
     with span(name, category=category, **attrs) as s:
         result = fn(*args)

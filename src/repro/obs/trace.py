@@ -331,11 +331,10 @@ class Tracer:
         }
 
     def breakdown(self, trace_id: str) -> Dict[str, float]:
-        """Flat per-phase seconds for benchmark artifacts.
+        """Flat per-phase seconds of one trace.
 
-        Always carries ``compile_s`` and ``execute_s`` (the fields the CI
-        bench-smoke job asserts on), plus wall/coverage and the remaining
-        categories.
+        Always carries ``compile_s`` and ``execute_s``, plus wall/coverage
+        and the remaining categories.
         """
         s = self.summary(trace_id)
         if s is None:

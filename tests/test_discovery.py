@@ -2,7 +2,7 @@
 
 The quality tests run with FK-name hints *stripped* (every column renamed
 ``col<j>``): recovery has to come from profiles and compiled containment
-checks, matching the honest setting ``BENCH_discovery.json`` reports.
+checks alone.
 Scoring is canonicalized through value-identical column classes — the
 synthetic dims carry a surrogate ``rid`` bit-identical to the id column,
 and joining on either is the same join.

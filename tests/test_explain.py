@@ -1,4 +1,4 @@
-"""EXPLAIN / EXPLAIN ANALYZE, device-memory accounting, trajectory gate.
+"""EXPLAIN / EXPLAIN ANALYZE and device-memory accounting.
 
 The acceptance bars this file enforces:
 
@@ -15,8 +15,6 @@ The acceptance bars this file enforces:
   byte-budget eviction never evicts the sole remaining entry.
 * the HTTP front end serves POST /v1/explain and GET /v1/traces, and the
   chrome trace export carries explicit download headers.
-* the perf-trajectory ``check()`` gate passes clean records, and fails
-  regressed ratios, missing grid cells, and lost breakdowns.
 """
 import json
 import math
@@ -265,40 +263,3 @@ def test_http_explain_traces_and_chrome_headers():
         server.shutdown()
         svc.close()
 
-
-# -- trajectory regression gate ----------------------------------------------
-
-def _cell(sf, churn, conc, **over):
-    rec = {"sf": sf, "churn": churn, "concurrency": conc,
-           "warm_speedup": 100.0, "refresh_speedup": 10.0,
-           "throughput_scaling": 2.0,
-           "breakdown": {"wall_s": 1.0, "compile_s": 0.5}}
-    rec.update(over)
-    return rec
-
-
-def test_trajectory_check_gate(tmp_path):
-    from benchmarks import trajectory
-    baseline = [_cell(1, 0.0, 1, refresh_speedup=None),
-                _cell(1, 0.01, 4)]
-    path = tmp_path / "baseline.json"
-    path.write_text(json.dumps(baseline))
-
-    clean = [_cell(1, 0.0, 1, refresh_speedup=None), _cell(1, 0.01, 4)]
-    assert trajectory.check(clean, str(path), rel_tol=0.5) == []
-
-    # a ratio below baseline * (1 - tol) fails with a readable message
-    slow = [_cell(1, 0.0, 1, refresh_speedup=None),
-            _cell(1, 0.01, 4, warm_speedup=40.0)]
-    failures = trajectory.check(slow, str(path), rel_tol=0.5)
-    assert len(failures) == 1 and "warm_speedup" in failures[0]
-
-    # shrinking the grid or losing the breakdown is itself a regression
-    failures = trajectory.check(clean[:1], str(path), rel_tol=0.5)
-    assert any("missing grid cells" in f for f in failures)
-    broken = [_cell(1, 0.0, 1, refresh_speedup=None),
-              _cell(1, 0.01, 4, breakdown=None,
-                    throughput_scaling=float("nan"))]
-    failures = trajectory.check(broken, str(path), rel_tol=0.5)
-    assert any("breakdown" in f for f in failures)
-    assert any("not finite" in f for f in failures)

@@ -1,6 +1,7 @@
 """Graph analytics subsystem: kernel/reference parity, algorithms vs the
-numpy ground truth, and the engine's extract->analyze loop with its
-content-addressed CSR cache.
+numpy ground truth, the engine's extract->analyze loop with its
+content-addressed CSR cache, and each dataset's served graph against numpy
+over the oracle's edges.
 """
 import numpy as np
 import pytest
@@ -264,3 +265,84 @@ def test_standalone_result_graph_view(engine):
     assert csr is detached.graph_view()            # memoized locally
     assert csr.num_vertices == sum(
         int(t.num_rows()) for t in res.vertices.values())
+
+
+# ---------------------------------------------------------------------------
+# Served analytics on each dataset's extracted graph, against numpy over
+# the oracle's edges: bench.reference's equijoins and dense numbering
+# (vertex labels in name order, ids ascending inside each)
+# ---------------------------------------------------------------------------
+
+def _datasets():
+    from repro.data import (dblp_model, fraud_model, imdb_model, make_dblp,
+                            make_imdb, make_tpcds)
+    return {"fraud": (lambda: fraud_model("store"),
+                      lambda: make_tpcds(sf=1, seed=0)),
+            "dblp": (dblp_model, lambda: make_dblp(scale=1, seed=1)),
+            "imdb": (imdb_model, lambda: make_imdb(scale=1, seed=2))}
+
+
+@pytest.fixture(scope="module")
+def served():
+    """name -> (engine, model, oracle src, oracle dst, vertex count)."""
+    from bench import reference
+    from oracle import graph_spec, tables
+    from repro.api import ExtractionEngine
+
+    memo = {}
+
+    def get(name):
+        if name not in memo:
+            model_fn, db_fn = _datasets()[name]
+            model, db = model_fn(), db_fn()
+            graph, data = graph_spec(model), tables(db)
+            numbering = reference.dense_numbering(data, graph)
+            src, dst = reference.dense_edges(
+                graph, reference.edge_bags(data, graph), numbering)
+            n = sum(len(ids) for _, ids in numbering.values())
+            memo[name] = (ExtractionEngine(db), model, src, dst, n)
+        return memo[name]
+    return get
+
+
+def _seeds(n):
+    return np.arange(0, n, max(1, n // 8))[:8]
+
+
+@pytest.mark.parametrize("algorithm", ["pagerank", "wcc", "khop",
+                                       "degree_stats"])
+@pytest.mark.parametrize("name", ["fraud", "dblp", "imdb"])
+def test_served_analytics_match_numpy_over_the_oracle(served, name,
+                                                      algorithm):
+    from bench import reference
+
+    engine, model, src, dst, n = served(name)
+    params = {"pagerank": {"iters": 15}, "khop": {"seeds": _seeds(n), "k": 2}}
+    res = engine.analyze(model, algorithm=algorithm,
+                         **params.get(algorithm, {}))
+    assert res.csr.num_vertices == n
+    valid = np.ones(len(src), bool)
+    if algorithm == "pagerank":
+        np.testing.assert_allclose(
+            np.asarray(res.values),
+            gref.pagerank_np(src, dst, valid, n, iters=15),
+            rtol=1e-4, atol=1e-8)
+    elif algorithm == "wcc":
+        np.testing.assert_array_equal(np.asarray(res.values),
+                                      gref.wcc_np(src, dst, valid, n))
+    elif algorithm == "khop":
+        seeds = np.zeros(n, bool)
+        seeds[_seeds(n)] = True
+        got = np.asarray(res.values)
+        np.testing.assert_array_equal(
+            got, gref.khop_np(src, dst, valid, seeds, n, k=2))
+        np.testing.assert_array_equal(
+            got, reference.khop(src, dst, _seeds(n), n, 2))
+    else:
+        want = gref.degree_stats_np(src, dst, valid, n)
+        for key in ("out_degree", "in_degree"):
+            np.testing.assert_array_equal(np.asarray(res.values[key]),
+                                          want[key])
+        for key in ("num_edges", "max_out_degree", "max_in_degree",
+                    "isolated"):
+            assert int(res.values[key]) == want[key], key

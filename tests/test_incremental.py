@@ -14,9 +14,7 @@ import pytest
 from repro.api import ExtractionEngine
 from repro.core.database import Database, compute_stats
 from repro.core.pipeline import PipelineCompiler
-from repro.data import make_dblp, make_imdb, make_tpcds
-from repro.data.dblp import dblp_model
-from repro.data.imdb import imdb_model
+from repro.data import make_tpcds
 from repro.data.tpcds import fraud_model, recommendation_model
 from repro.incremental.changelog import ChangeLog, TableDelta, merge_deltas
 from repro.incremental.delta import (
@@ -339,111 +337,9 @@ def test_query_delta_terms_versions():
 
 
 # ---------------------------------------------------------------------------
-# refresh parity (the acceptance contract)
+# refresh parity (the acceptance contract; the matrix of models and churn
+# kinds against the numpy oracle is in test_refresh_oracle_*.py)
 # ---------------------------------------------------------------------------
-
-def _scripted_churn_parity(db, model, churn_rounds, engine=None):
-    engine = engine or ExtractionEngine(db, auto_refresh=True)
-    r = engine.extract(model)
-    assert r.refresh.path == "cold"
-    paths = []
-    for mutate in churn_rounds:
-        mutate(db)
-        r = engine.extract(model)
-        paths.append(r.refresh.path)
-        oracle = _oracle(db, model)
-        assert _graph_digests(r.graph) == _graph_digests(oracle.graph), \
-            f"digest divergence on path {r.refresh.path}"
-    return engine, paths
-
-
-def test_refresh_parity_tpcds_fraud():
-    db = make_tpcds(sf=1, seed=0)
-    rng = np.random.default_rng(1)
-    engine, paths = _scripted_churn_parity(db, fraud_model("store"), [
-        lambda d: _churn_tpcds(d, rng, n_ins=10, n_del=0),   # inserts
-        lambda d: _churn_tpcds(d, rng, n_ins=0, n_del=8),    # deletes
-        lambda d: _churn_tpcds(d, rng, n_ins=10, n_del=8),   # mixed
-        # dimension churn: new items shift the vertex set too
-        lambda d: d.insert_rows(
-            "item",
-            rid=np.arange(10_000, 10_003, dtype=np.int32),
-            i_id=np.arange(10_000, 10_003, dtype=np.int32),
-            i_price=np.array([1, 2, 3], np.int32)),
-    ])
-    assert paths == ["delta"] * 4
-    # a second engine pays cold; this one served every round incrementally
-    assert engine.cache_info()["results"] == 1
-
-
-@pytest.mark.slow
-def test_refresh_parity_dblp_through_maintained_views():
-    db = make_dblp(scale=1, seed=1)
-    engine = ExtractionEngine(db, auto_refresh=True)
-    model = dblp_model()
-    first = engine.extract(model)
-    assert first.refresh.path == "cold"
-    rng = np.random.default_rng(2)
-
-    def churn_wrote(d):
-        n = int(np.asarray(d.tables["wrote"]["rid"]).max()) + 1
-        d.insert_rows(
-            "wrote",
-            rid=np.arange(n, n + 25, dtype=np.int32),
-            a_sk=rng.integers(0, d.stats["author"].rows, 25).astype(np.int32),
-            p_sk=rng.integers(0, d.stats["paper"].rows, 25).astype(np.int32))
-        live = np.flatnonzero(np.asarray(d.tables["wrote"].valid))
-        mask = np.zeros(d.tables["wrote"].capacity, dtype=bool)
-        mask[rng.choice(live, 20, replace=False)] = True
-        d.delete_rows("wrote", mask)
-
-    churn_wrote(db)
-    r = engine.extract(model)
-    assert r.refresh.path == "delta"
-    oracle = _oracle(db, model)
-    assert _graph_digests(r.graph) == _graph_digests(oracle.graph)
-
-    # if the plan materialized views, they must have been maintained in
-    # place — and their content must equal a fresh materialization
-    if first.provenance.views_built:
-        assert r.refresh.views_maintained
-        from repro.core.executor import execute_query
-        for sig, cv in engine._views.items():
-            from repro.core.jsmv import ViewDef
-            fresh = execute_query(Database(dict(db.tables)),
-                                  ViewDef(cv.name, cv.pattern).as_query())
-            assert table_digest(cv.table) == table_digest(fresh)
-
-        # a follow-up request that *reads* the maintained views (fresh
-        # plan, cached views adopted as free JS-MV rewrites): still exact
-        r2 = engine.extract(model, method="extgraph-mv", auto_refresh=False)
-        assert _graph_digests(r2.graph) == _graph_digests(
-            _oracle(db, model, method="extgraph-mv").graph)
-
-
-@pytest.mark.slow
-def test_refresh_parity_imdb():
-    db = make_imdb(scale=1, seed=2)
-    rng = np.random.default_rng(3)
-
-    def churn_directs(d):
-        n = int(np.asarray(d.tables["directs"]["rid"]).max()) + 1
-        d.insert_rows(
-            "directs",
-            rid=np.arange(n, n + 15, dtype=np.int32),
-            per_sk=rng.integers(0, d.stats["person"].rows, 15).astype(np.int32),
-            m_sk=rng.integers(0, d.stats["movie"].rows, 15).astype(np.int32))
-
-    def delete_acts(d):
-        live = np.flatnonzero(np.asarray(d.tables["acts"].valid))
-        mask = np.zeros(d.tables["acts"].capacity, dtype=bool)
-        mask[rng.choice(live, 30, replace=False)] = True
-        d.delete_rows("acts", mask)
-
-    _, paths = _scripted_churn_parity(db, imdb_model(),
-                                      [churn_directs, delete_acts])
-    assert paths == ["delta", "delta"]
-
 
 @pytest.mark.slow
 def test_refresh_parity_kernel_and_bloom_path():
@@ -458,33 +354,6 @@ def test_refresh_parity_kernel_and_bloom_path():
     assert r.refresh.path == "delta"
     oracle = _oracle(db, model)
     assert _graph_digests(r.graph) == _graph_digests(oracle.graph)
-
-
-def test_refresh_after_inserts_beyond_the_planned_key_ranges():
-    """Items keyed beyond every range the programs planned, and sales of
-    them: each delta refresh is exact.  The second round's delta terms
-    run the programs memoized in the first, whose ranges cover the first
-    round's keys only: their stale steps re-run by bisection."""
-    db = make_tpcds(sf=1, seed=0)
-    compiler = PipelineCompiler()
-    engine = ExtractionEngine(db, compiler=compiler, auto_refresh=True)
-    model = fraud_model("store")
-    engine.extract(model)
-
-    def one(v):
-        return np.array([v], np.int32)
-
-    for _ in range(2):
-        key = db.stats["item"].minmax["i_id"][1] + 3000
-        rid = db.stats["store_sales"].minmax["rid"][1] + 1
-        db.insert_rows("item", rid=one(key), i_id=one(key), i_price=one(1))
-        db.insert_rows("store_sales", rid=one(rid), c_sk=one(3),
-                       i_sk=one(key), p_sk=one(0), o_sk=one(1))
-        r = engine.extract(model)
-        assert r.refresh.path == "delta"
-        assert _graph_digests(r.graph) == \
-            _graph_digests(_oracle(db, model).graph)
-    assert compiler.stats["retries"] > 0
 
 
 def test_refresh_paths_noop_threshold_and_fallbacks():

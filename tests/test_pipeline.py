@@ -384,77 +384,3 @@ def test_capacity_counters_count_one_extract(dblp_db, monkeypatch):
     assert value("pipeline_capacity_rows_total", rows="allotted") == sum(
         s.capacity for s in steps)
 
-
-# -- range probes: a table of prefix counts over the build key's range -------
-
-def _probe_rows(method):
-    from repro import obs
-
-    return obs.REGISTRY.value("pipeline_probe_rows_total", method=method)
-
-
-def _item_row(db, key):
-    """One ``item`` row, a copy of the first, keyed ``key``."""
-    row = {c: v[:1].copy() for c, v in db.tables["item"].to_numpy().items()}
-    row["i_item_sk"][:] = key
-    return row
-
-
-def _keyed_sales(db):
-    """Probe rows with a key of the fraud unit's step into ``item``."""
-    sales = db.tables["store_sales"].to_numpy()["ss_item_sk"]
-    return int((sales != np.iinfo(np.int32).max).sum())
-
-
-def test_stale_range_falls_back_to_the_bisection(monkeypatch):
-    """A build key inserted beyond the planned range (stats not
-    re-analyzed, the memoized program kept) is counted on the device: the
-    unit re-runs once with that step bisecting, to the exact bag, and the
-    step's probe rows count as searched."""
-    from repro import obs
-    from repro.obs.metrics import MetricsRegistry
-
-    monkeypatch.setattr(obs, "REGISTRY", MetricsRegistry())
-    db, model = _bench_fraud(shrink=200)
-    comp = PipelineCompiler()
-    engine = ExtractionEngine(db, compiler=comp)
-    engine.extract(model)
-    prog = next(p for p in _programs_run(engine) if p.kind == "merged")
-    assert None not in prog.step_ranges()
-    assert _probe_rows("search") == 0 and _probe_rows("range") > 0
-    kmin, span = prog.ranges[0]                       # the step into item
-    db.insert_rows("item", **_item_row(db, kmin + span))
-    rows = {m: _probe_rows(m) for m in ("range", "search")}
-    got = engine.extract(model)
-    assert comp.stats["retries"] == 1
-    eager = ExtractionEngine(db, compiled=False).extract(model)
-    assert _digests(got.edges) == _digests(eager.edges)
-    assert _probe_rows("search") == _keyed_sales(db)
-    assert _probe_rows("range") > rows["range"]
-    kept = comp._unit_memo[("merged", prog.unit)]
-    assert kept.ranges == (None,) + prog.ranges[1:]
-    engine.extract(model)
-    assert comp.stats["retries"] == 1
-
-
-def test_sparse_key_keeps_the_bisection(monkeypatch):
-    """A build key whose span is over RANGE_SPAN_FACTOR times the step's
-    rows bisects from the start: no retry, its rows count as searched."""
-    from repro import obs
-    from repro.core.pipeline import RANGE_SPAN_FACTOR
-    from repro.obs.metrics import MetricsRegistry
-
-    monkeypatch.setattr(obs, "REGISTRY", MetricsRegistry())
-    db, model = _bench_fraud(shrink=200)
-    far = RANGE_SPAN_FACTOR * db.tables["store_sales"].capacity + 10
-    db.insert_rows("item", **_item_row(db, far))
-    comp = PipelineCompiler()
-    engine = ExtractionEngine(db, compiler=comp)
-    got = engine.extract(model)
-    prog = next(p for p in _programs_run(engine) if p.kind == "merged")
-    assert prog.ranges[0] is None and None not in prog.ranges[1:]
-    assert comp.stats["retries"] == 0
-    assert _probe_rows("search") == _keyed_sales(db)
-    assert _probe_rows("range") > 0
-    eager = ExtractionEngine(db, compiled=False).extract(model)
-    assert _digests(got.edges) == _digests(eager.edges)
